@@ -3,43 +3,22 @@
 ASIC: throughput vs PE-array rows/cols.  TPU analogues:
   (a) tile size T (rows of the array == vertices per tile) — blocked
       SpMM time vs T at fixed graph;
-  (b) ring width P (pod-level RER): devices in the rotation, via a
-      subprocess with forced host devices — wall time of the sharded
-      ring aggregate vs P."""
+  (b) ring width P (pod-level RER): devices in the rotation — wall time
+      of the sharded ring aggregate over the first P devices of this
+      process (`ring_mesh(P)`).  One process drives every ring size, so
+      the accelerator is opened once."""
 from __future__ import annotations
 
-import os
-import subprocess
-import sys
-import textwrap
-
+import jax
 import jax.numpy as jnp
+import numpy as np
 
 from benchmarks.common import emit, pick, scaled, time_fn
+from repro.core.dataflow import make_ring_aggregate, shard_adjacency_for_ring
+from repro.distributed.sharding import ring_mesh
 from repro.graphs.format import coo_to_blocked
 from repro.graphs.generate import rmat_graph, random_features
 from repro.kernels.rer_spmm import ops as spmm_ops
-
-_RING = textwrap.dedent("""
-    import os, time, sys
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    import numpy as np, jax, jax.numpy as jnp
-    from repro.core.dataflow import make_ring_aggregate, shard_adjacency_for_ring
-    n, f = 1024, 64
-    rng = np.random.default_rng(0)
-    a = (rng.random((n, n)) < 0.05).astype(np.float32)
-    x = rng.standard_normal((n, f)).astype(np.float32)
-    ps = tuple(int(p) for p in os.environ.get("RING_PS", "1,2,4,8").split(","))
-    for p in ps:
-        mesh = jax.make_mesh((p,), ("ring",))
-        blocks = jnp.asarray(shard_adjacency_for_ring(a, p))
-        fn = jax.jit(make_ring_aggregate(mesh, "ring"))
-        y = jax.block_until_ready(fn(blocks, jnp.asarray(x)))
-        t0 = time.perf_counter();
-        for _ in range(5): y = jax.block_until_ready(fn(blocks, jnp.asarray(x)))
-        t = (time.perf_counter() - t0) / 5 * 1e6
-        print(f"RING,{p},{t:.1f}")
-""")
 
 
 def run():
@@ -56,13 +35,14 @@ def run():
         emit(f"fig17a/tile_{t}/spmm_us", round(us, 1),
              f"nnzb={b.nnzb} density={b.density():.3f}")
 
-    from benchmarks import common
-    env = dict(os.environ)
-    env["PYTHONPATH"] = "src"
-    env["RING_PS"] = "1,2" if common.SMOKE else "1,2,4,8"
-    r = subprocess.run([sys.executable, "-c", _RING], env=env,
-                       capture_output=True, text=True, timeout=600)
-    for line in r.stdout.splitlines():
-        if line.startswith("RING,"):
-            _, p, us = line.split(",")
-            emit(f"fig17b/ring_devices_{p}/us", us, "")
+    n, f = 1024, 64
+    rng = np.random.default_rng(0)
+    a = (rng.random((n, n)) < 0.05).astype(np.float32)
+    x = jnp.asarray(rng.standard_normal((n, f)).astype(np.float32))
+    for p in pick((1, 2, 4, 8), 2):
+        if p > len(jax.devices()):
+            break
+        blocks = jnp.asarray(shard_adjacency_for_ring(a, p))
+        fn = jax.jit(make_ring_aggregate(ring_mesh(p), "ring"))
+        emit(f"fig17b/ring_devices_{p}/us", round(time_fn(fn, blocks, x), 1),
+             "")

@@ -75,6 +75,8 @@ BENCHES = {
 
 
 def main() -> int:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default="",
                     help="comma-separated figure keys (default: all)")

@@ -51,7 +51,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.graphs.format import COOGraph
 from repro.graphs.partition import (build_tile_store, merge_by_key,
@@ -61,13 +60,6 @@ from repro.graphs.partition import (build_tile_store, merge_by_key,
 def _ring_step_perm(p: int):
     # receive from the southern neighbour: (i+1) % p sends to i
     return [((i + 1) % p, i) for i in range(p)]
-
-
-def _pvary(x, axis_name: str):
-    """Mark a carry as device-varying (shard_map vma semantics; jax <
-    0.6 has no varying-manual-axes tracking, so this is an identity)."""
-    pvary = getattr(jax.lax, "pvary", None)
-    return pvary(x, (axis_name,)) if pvary is not None else x
 
 
 # ----------------------------------------------------------------------
@@ -90,7 +82,7 @@ def ring_aggregate_dense(a_blocks: jnp.ndarray, x_shard: jnp.ndarray,
                 else jnp.full(x_shard.shape, -jnp.inf, jnp.float32))
     # mark the carry as device-varying so the fori_loop carry types match
     # after the ppermute
-    init_acc = _pvary(init_acc, axis_name)
+    init_acc = jax.lax.pcast(init_acc, axis_name, to="varying")
 
     def body(k, carry):
         x_rot, acc = carry
@@ -144,9 +136,9 @@ def make_ring_aggregate(mesh: Mesh, axis: str, op: str = "sum") -> Callable:
         # a_blocks arrives as (1, P, n_loc, n_loc) per device; squeeze.
         return fn(a_blocks[0], x)
 
-    sm = shard_map(inner, mesh=mesh,
-                   in_specs=(P(axis, None, None, None), P(axis, None)),
-                   out_specs=P(axis, None))
+    sm = jax.shard_map(inner, mesh=mesh,
+                       in_specs=(P(axis, None, None, None), P(axis, None)),
+                       out_specs=P(axis, None))
 
     def call(a_blocks, x):
         if a_blocks.shape[0] != p or a_blocks.shape[1] != p:
@@ -546,7 +538,7 @@ def _ring_packed_shard(rows, cols, vals, x_shard, counts, *,
         init_acc = jnp.zeros((n_loc, f), jnp.float32)
     else:
         init_acc = jnp.full((n_loc, f), -jnp.inf, jnp.float32)
-    init_acc = _pvary(init_acc, axis_name)
+    init_acc = jax.lax.pcast(init_acc, axis_name, to="varying")
 
     def step(carry, k):
         x_rot, acc = carry
@@ -596,7 +588,7 @@ def make_ring_packed_aggregate(mesh: Mesh, axis: str, op: str,
         # leading P dim arrives size-1 per device; squeeze it
         return body(rows[0], cols[0], vals[0], x, counts[0])
 
-    return shard_map(
+    return jax.shard_map(
         inner, mesh=mesh,
         in_specs=(P(axis, None, None), P(axis, None, None),
                   P(axis, None, None), P(axis, None), P(axis, None)),
@@ -625,7 +617,7 @@ def _ring_tiled_shard(blocks, tile_row, tile_col, x_shard, counts, *,
         init_acc = jnp.zeros((q_loc, tile, f), jnp.float32)
     else:
         init_acc = jnp.full((q_loc, tile, f), -jnp.inf, jnp.float32)
-    init_acc = _pvary(init_acc, axis_name)
+    init_acc = jax.lax.pcast(init_acc, axis_name, to="varying")
 
     def step(carry, k):
         x_rot, acc = carry
@@ -680,7 +672,7 @@ def make_ring_tiled_aggregate(mesh: Mesh, axis: str, op: str,
         # leading P dim arrives size-1 per device; squeeze it
         return body(blocks[0], tile_row[0], tile_col[0], x, counts[0])
 
-    return shard_map(
+    return jax.shard_map(
         inner, mesh=mesh,
         in_specs=(P(axis, None, None, None, None), P(axis, None, None),
                   P(axis, None, None), P(axis, None), P(axis, None)),
@@ -705,8 +697,8 @@ def _ring_typed_tiled_shard(blocks, tile_row, tile_col, tile_rel,
     p, r = num_shards, num_relations
     me = jax.lax.axis_index(axis_name)
     h = x_shard.shape[1] // r
-    init_acc = _pvary(jnp.zeros((q_loc, tile, h), jnp.float32),
-                      axis_name)
+    init_acc = jax.lax.pcast(jnp.zeros((q_loc, tile, h), jnp.float32),
+                             axis_name, to="varying")
 
     def step(carry, k):
         x_rot, acc = carry
@@ -749,7 +741,7 @@ def make_ring_typed_sum_tiled(mesh: Mesh, axis: str, q_loc: int,
         return body(blocks[0], tile_row[0], tile_col[0], tile_rel[0],
                     x, counts[0])
 
-    return shard_map(
+    return jax.shard_map(
         inner, mesh=mesh,
         in_specs=(P(axis, None, None, None, None), P(axis, None, None),
                   P(axis, None, None), P(axis, None, None),
@@ -765,7 +757,8 @@ def _ring_typed_packed_shard(rows, cols, vals, rels, x_shard, counts, *,
     p, r = num_shards, num_relations
     me = jax.lax.axis_index(axis_name)
     h = x_shard.shape[1] // r
-    init_acc = _pvary(jnp.zeros((n_loc, h), jnp.float32), axis_name)
+    init_acc = jax.lax.pcast(jnp.zeros((n_loc, h), jnp.float32),
+                             axis_name, to="varying")
 
     def step(carry, k):
         x_rot, acc = carry
@@ -801,7 +794,7 @@ def make_ring_typed_sum_packed(mesh: Mesh, axis: str, n_loc: int,
     def inner(rows, cols, vals, rels, x, counts):
         return body(rows[0], cols[0], vals[0], rels[0], x, counts[0])
 
-    return shard_map(
+    return jax.shard_map(
         inner, mesh=mesh,
         in_specs=(P(axis, None, None), P(axis, None, None),
                   P(axis, None, None), P(axis, None, None),
@@ -820,8 +813,8 @@ def _ring_gated_tiled_shard(blocks, tile_row, tile_col, ph_shard,
     me = jax.lax.axis_index(axis_name)
     f = pcx_shard.shape[1] // 2
     ph_t = ph_shard.reshape(q_loc, tile, f)
-    init_acc = _pvary(jnp.zeros((q_loc, tile, f), jnp.float32),
-                      axis_name)
+    init_acc = jax.lax.pcast(jnp.zeros((q_loc, tile, f), jnp.float32),
+                             axis_name, to="varying")
 
     def step(carry, k):
         x_rot, acc = carry
@@ -863,7 +856,7 @@ def make_ring_gated_tiled(mesh: Mesh, axis: str, q_loc: int,
         return body(blocks[0], tile_row[0], tile_col[0], ph, pcx,
                     counts[0])
 
-    return shard_map(
+    return jax.shard_map(
         inner, mesh=mesh,
         in_specs=(P(axis, None, None, None, None), P(axis, None, None),
                   P(axis, None, None), P(axis, None), P(axis, None),
@@ -877,7 +870,8 @@ def _ring_gated_packed_shard(rows, cols, vals, ph_shard, pcx_shard,
     p = num_shards
     me = jax.lax.axis_index(axis_name)
     f = pcx_shard.shape[1] // 2
-    init_acc = _pvary(jnp.zeros((n_loc, f), jnp.float32), axis_name)
+    init_acc = jax.lax.pcast(jnp.zeros((n_loc, f), jnp.float32),
+                             axis_name, to="varying")
 
     def step(carry, k):
         x_rot, acc = carry
@@ -911,7 +905,7 @@ def make_ring_gated_packed(mesh: Mesh, axis: str, n_loc: int) -> Callable:
     def inner(rows, cols, vals, ph, pcx, counts):
         return body(rows[0], cols[0], vals[0], ph, pcx, counts[0])
 
-    return shard_map(
+    return jax.shard_map(
         inner, mesh=mesh,
         in_specs=(P(axis, None, None), P(axis, None, None),
                   P(axis, None, None), P(axis, None), P(axis, None),

@@ -20,7 +20,9 @@ engine's per-batch dicts) call it once at their entry point.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Optional
+from typing import Any, Callable, Dict, List, Optional, Tuple
+
+import jax
 
 
 @dataclasses.dataclass(eq=False)
@@ -111,3 +113,24 @@ def wrap_plan(carrier: Dict[str, Any]) -> PreparedPlan:
         footprint_bytes=footprint,
         autotune=meta.get("format_choice"),
     )
+
+
+def split_arrays(tree: Any) -> Tuple[List[jax.Array], Callable]:
+    """`(arrays, rebuild)`: the `jax.Array` leaves of `tree` (a carrier
+    dict, features, labels ...) and the function that puts replacements
+    back.  A jitted function that closes over device arrays bakes them
+    into its program as constants — a 1.2 GB dense tile plan becomes a
+    1.2 GB literal to compile — so jitted steps take `arrays` as an
+    argument and call `rebuild(arrays)` inside.  Every other leaf (ints,
+    meshes, ring functions, executors) stays static."""
+    leaves, treedef = jax.tree_util.tree_flatten(tree)
+    idx = [i for i, leaf in enumerate(leaves)
+           if isinstance(leaf, jax.Array)]
+
+    def rebuild(arrays):
+        out = list(leaves)
+        for i, a in zip(idx, arrays):
+            out[i] = a
+        return jax.tree_util.tree_unflatten(treedef, out)
+
+    return [leaves[i] for i in idx], rebuild

@@ -20,10 +20,17 @@ dst-sorted and padded to one uniform pow2 nnz bucket S, with
 
   tile_ptr (q+1,) int32   — interval i owns tiles [ptr[i], ptr[i+1])
   tile_src (K,)   int32   — each tile's source interval
-  rows/cols/vals (K, S)   — packed entries (pad val = 0.0)
+  rows/cols/vals (K, S)   — packed entries (pad val = 0.0), handed to
+                            the kernel as lane-major (K, R, L) slabs
+                            of whole 128-lane rows
+                            (`rer_gather.entry_slabs`): a whole-slab DMA
+                            per tile is legal for every bucket S, where
+                            a one-row slice of a tiled (K, S) array is
+                            not, and the one-hot selectors stay (T, 128)
+                            however heavy the hub tile
 
 Scalar-prefetched `tile_ptr`/`tile_src` drive the walk; the entry
-arrays and the feature matrix stay in HBM (`pltpu.ANY`) and are DMA'd
+arrays and the feature matrix stay in HBM (`pl.ANY`) and are DMA'd
 slab-by-slab.  Sum only: the one-hot MXU gather/scatter spelling needs
 no (S, T, Fc) candidate tensor for sum, and the streamed max keeps its
 own residual-capturing path (DESIGN.md C9).  On CPU the kernel runs in
@@ -40,12 +47,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _one_hot(idx: jnp.ndarray, t: int) -> jnp.ndarray:
-    """(S,) int32 -> (S, T) float32 selector via broadcasted iota (the
-    Pallas-safe one-hot: contractions run on the MXU, no scatter)."""
-    iota = jax.lax.broadcasted_iota(jnp.int32, (idx.shape[0], t), 1)
-    return (idx[:, None] == iota).astype(jnp.float32)
+from repro.kernels.rer_gather.rer_gather import LANES, entry_slabs, slab_sum
 
 
 def _queue_kernel(tile_ptr, tile_src, rows_hbm, cols_hbm, vals_hbm,
@@ -59,12 +61,12 @@ def _queue_kernel(tile_ptr, tile_src, rows_hbm, cols_hbm, vals_hbm,
         """The four async copies that stage tile k into VMEM slot
         `slot`: its entry slab and its source-feature block."""
         return (
-            pltpu.make_async_copy(rows_hbm.at[pl.ds(k, 1)],
-                                  rrows.at[slot], sems.at[slot, 0]),
-            pltpu.make_async_copy(cols_hbm.at[pl.ds(k, 1)],
-                                  rcols.at[slot], sems.at[slot, 1]),
-            pltpu.make_async_copy(vals_hbm.at[pl.ds(k, 1)],
-                                  rvals.at[slot], sems.at[slot, 2]),
+            pltpu.make_async_copy(rows_hbm.at[k], rrows.at[slot],
+                                  sems.at[slot, 0]),
+            pltpu.make_async_copy(cols_hbm.at[k], rcols.at[slot],
+                                  sems.at[slot, 1]),
+            pltpu.make_async_copy(vals_hbm.at[k], rvals.at[slot],
+                                  sems.at[slot, 2]),
             pltpu.make_async_copy(
                 x_hbm.at[pl.ds(tile_src[k] * t, t),
                          pl.ds(j * fc, fc)],
@@ -93,14 +95,8 @@ def _queue_kernel(tile_ptr, tile_src, rows_hbm, cols_hbm, vals_hbm,
             start(k + 1, 1 - slot)
 
         wait(k, slot)
-        rows_s = rrows[slot, 0]
-        cols_s = rcols[slot, 0]
-        vals_s = rvals[slot, 0]
-        gathered = jnp.dot(_one_hot(cols_s, t), rx[slot],
-                           preferred_element_type=jnp.float32)  # (S, Fc)
-        scaled = vals_s[:, None] * gathered                     # pad: 0.0
-        return acc + jnp.dot(_one_hot(rows_s, t).T, scaled,
-                             preferred_element_type=jnp.float32)
+        return slab_sum(rrows.at[slot], rcols.at[slot], rvals.at[slot],
+                        rx[slot], acc)
 
     acc = jax.lax.fori_loop(lo, hi, body,
                             jnp.zeros((t, fc), jnp.float32))
@@ -120,36 +116,41 @@ def chunk_queue_spmm(tile_ptr: jnp.ndarray, tile_src: jnp.ndarray,
     """Y[i*T:(i+1)*T] = act(sum over the queue span of interval i of
     scatter(rows, vals * X[src*T + cols])) — the persistent sum sweep.
 
-    x must be (q_src*T, F) with F a multiple of `feature_chunk` (pad
-    before calling; `ops.chunk_queue_aggregate` does).
+    x must be (q_src*T, F).  Feature chunks are whole 128-lane
+    multiples (the per-tile x DMA refuses a narrower column slice), so
+    F is zero-padded up to the chunk and the result sliced back.
     """
-    k_tiles, s = rows.shape
     n_src, f = x.shape
     assert n_src % t == 0, (n_src, t)
-    fc = min(feature_chunk, f)
-    assert f % fc == 0, (f, fc)
-    grid = (q_dst, f // fc)
-    return pl.pallas_call(
+    fc = -(-min(feature_chunk, f) // LANES) * LANES
+    f_pad = -(-f // fc) * fc
+    if f_pad != f:
+        x = jnp.pad(x, ((0, 0), (0, f_pad - f)))
+    rows, cols, vals = (entry_slabs(a) for a in (rows, cols, vals))
+    slab = rows.shape[1:]
+    grid = (q_dst, f_pad // fc)
+    y = pl.pallas_call(
         partial(_queue_kernel, t=t, fc=fc, activation=activation),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=grid,
             in_specs=[
-                pl.BlockSpec(memory_space=pltpu.ANY),   # rows (K, S)
-                pl.BlockSpec(memory_space=pltpu.ANY),   # cols (K, S)
-                pl.BlockSpec(memory_space=pltpu.ANY),   # vals (K, S)
-                pl.BlockSpec(memory_space=pltpu.ANY),   # x (q*T, F)
+                pl.BlockSpec(memory_space=pl.ANY),   # rows (K, R, L)
+                pl.BlockSpec(memory_space=pl.ANY),   # cols (K, R, L)
+                pl.BlockSpec(memory_space=pl.ANY),   # vals (K, R, L)
+                pl.BlockSpec(memory_space=pl.ANY),   # x (q*T, F)
             ],
             out_specs=pl.BlockSpec((t, fc),
                                    lambda i, j, ptr, src: (i, j)),
             scratch_shapes=[
-                pltpu.VMEM((2, 1, s), jnp.int32),       # rows slab x2
-                pltpu.VMEM((2, 1, s), jnp.int32),       # cols slab x2
-                pltpu.VMEM((2, 1, s), jnp.float32),     # vals slab x2
+                pltpu.VMEM((2,) + slab, jnp.int32),     # rows slab x2
+                pltpu.VMEM((2,) + slab, jnp.int32),     # cols slab x2
+                pltpu.VMEM((2,) + slab, jnp.float32),   # vals slab x2
                 pltpu.VMEM((2, t, fc), jnp.float32),    # x block x2
                 pltpu.SemaphoreType.DMA((2, 4)),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((q_dst * t, f), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((q_dst * t, f_pad), jnp.float32),
         interpret=interpret,
     )(tile_ptr, tile_src, rows, cols, vals, x)
+    return y[:, :f]

@@ -332,19 +332,17 @@ def tile_queue_aggregate(tq: TileQueue, x, *,
                          interpret: Optional[bool] = None,
                          activation: Optional[str] = None):
     """Run the persistent Mosaic walker over a built tile queue: pads
-    x to the (q*T, F-multiple-of-chunk) shape the kernel wants and
-    slices the result back to n rows."""
+    x to the q*T rows the kernel wants and slices the result back to n
+    rows."""
     from repro.kernels.chunk_queue.chunk_queue import chunk_queue_spmm
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
-    t, q = tq.tile, tq.q
-    n, f = x.shape
-    fc = min(feature_chunk, f)
-    pad_f = (-f) % fc
-    pad_n = q * t - n
-    if pad_f or pad_n:
-        x = jnp.pad(x, ((0, pad_n), (0, pad_f)))
+    n = x.shape[0]
+    pad_n = tq.q * tq.tile - n
+    if pad_n:
+        x = jnp.pad(x, ((0, pad_n), (0, 0)))
     y = chunk_queue_spmm(tq.tile_ptr, tq.tile_src, tq.rows, tq.cols,
-                         tq.vals, x, t=t, q_dst=q, feature_chunk=fc,
+                         tq.vals, x, t=tq.tile, q_dst=tq.q,
+                         feature_chunk=feature_chunk,
                          interpret=interpret, activation=activation)
-    return y[:n, :f]
+    return y[:n]

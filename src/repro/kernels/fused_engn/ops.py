@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.fused_engn.fused_engn import fused_extract_aggregate
+from repro.kernels.reference_vjp import with_reference_vjp
 
 
 def _is_cpu() -> bool:
@@ -46,11 +47,15 @@ def _fused_xla(blocks, block_row, block_col, x, w, *, q):
 def fused_engn_layer(blocks, block_row, block_col, x, w, *, q: int,
                      h_chunk: int = 256, interpret: bool | None = None,
                      impl: str | None = None):
+    """Y = A (X W): Mosaic kernel on TPU, `_fused_xla` elsewhere; the
+    kernel's reverse pass (to x and w) is the VJP of `_fused_xla`."""
+    reference = partial(_fused_xla, q=q)
     if impl is None:
         impl = "xla" if _is_cpu() else "pallas"
     if impl == "xla":
-        return _fused_xla(blocks, block_row, block_col, x, w, q=q)
+        return reference(blocks, block_row, block_col, x, w)
     if interpret is None:
         interpret = _is_cpu()
-    return _fused_jit(blocks, block_row, block_col, x, w, q=q,
-                      h_chunk=h_chunk, interpret=interpret)
+    kernel = partial(_fused_jit, q=q, h_chunk=h_chunk, interpret=interpret)
+    return with_reference_vjp(kernel, reference, 3)(
+        blocks, block_row, block_col, x, w)

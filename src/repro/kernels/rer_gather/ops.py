@@ -23,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.graphs.partition import PackedTileStore, pow2_bucket
+from repro.kernels.reference_vjp import with_reference_vjp
 from repro.kernels.rer_gather.rer_gather import rer_gather
 
 
@@ -193,19 +194,21 @@ def packed_spmm(rows, cols, vals, block_row, block_col, x, *, q: int,
                 finish: bool = True):
     """Full-graph packed SpMM: x (q*T, F) -> y (q*T, F).  Mosaic Pallas
     kernel on TPU, XLA gather+segment elsewhere; `finish=False` keeps
-    -inf in uncovered max rows (for callers that merge partials)."""
+    -inf in uncovered max rows (for callers that merge partials).  The
+    kernel's reverse pass is the VJP of `packed_spmm_xla`."""
     t = x.shape[0] // q
+    reference = partial(packed_spmm_xla, q=q, t=t, op=op, finish=finish)
     if impl is None:
         impl = "xla" if _is_cpu() else "pallas"
     if impl == "xla":
-        return packed_spmm_xla(rows, cols, vals, block_row, block_col, x,
-                               q=q, t=t, op=op, finish=finish)
+        return reference(rows, cols, vals, block_row, block_col, x)
     if interpret is None:
         interpret = _is_cpu()
-    return _packed_spmm_pallas(rows, cols, vals, block_row, block_col, x,
-                               q=q, t=t, op=op,
-                               feature_chunk=feature_chunk,
-                               interpret=interpret, finish=finish)
+    kernel = partial(_packed_spmm_pallas, q=q, t=t, op=op,
+                     feature_chunk=feature_chunk, interpret=interpret,
+                     finish=finish)
+    return with_reference_vjp(kernel, reference, 5)(
+        rows, cols, vals, block_row, block_col, x)
 
 
 def packed_tile_part(rows, cols, vals, xs, *, op: str = "sum",

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.reference_vjp import with_reference_vjp
 from repro.kernels.rer_spmm.rer_spmm import rer_spmm
 
 
@@ -89,12 +90,16 @@ def blocked_spmm(blocks, block_row, block_col, x, *, q: int, op: str = "sum",
                  feature_chunk: int = 512, interpret: bool | None = None,
                  impl: str | None = None):
     """Dispatch: Mosaic Pallas kernel on TPU, XLA tiled path elsewhere.
-    Pass impl="pallas" to force the kernel (interpret mode on CPU)."""
+    Pass impl="pallas" to force the kernel (interpret mode on CPU).  The
+    kernel's reverse pass is the VJP of `blocked_spmm_xla`."""
+    reference = partial(blocked_spmm_xla, q=q, op=op)
     if impl is None:
         impl = "xla" if _is_cpu() else "pallas"
     if impl == "xla":
-        return blocked_spmm_xla(blocks, block_row, block_col, x, q=q, op=op)
+        return reference(blocks, block_row, block_col, x)
     if interpret is None:
         interpret = _is_cpu()
-    return _blocked_spmm_jit(blocks, block_row, block_col, x, q=q, op=op,
-                             feature_chunk=feature_chunk, interpret=interpret)
+    kernel = partial(_blocked_spmm_jit, q=q, op=op,
+                     feature_chunk=feature_chunk, interpret=interpret)
+    return with_reference_vjp(kernel, reference, 3)(
+        blocks, block_row, block_col, x)

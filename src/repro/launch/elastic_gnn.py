@@ -51,6 +51,8 @@ class ElasticGNNTrainer:
         self.steps = steps
         self.strike_limit = int(strike_limit)
         self.plan = None
+        self.loss_fn = None
+        self.consts = ()
         self._jit_step = None
         self.stats: Dict[str, Any] = {
             "remesh_count": 0, "remesh_s": 0.0, "strikes": 0,
@@ -76,6 +78,7 @@ class ElasticGNNTrainer:
         still degrade the result to the tiled streamed backend."""
         from repro.core.engn import prepare_graph
         from repro.core.models import apply_stack
+        from repro.core.plan import split_arrays
         from repro.training.train_lib import make_gnn_train_step
         import jax
         import jax.numpy as jnp
@@ -85,15 +88,21 @@ class ElasticGNNTrainer:
                 layer.cfg.ring_shards = int(num_shards)
         self.plan = prepare_graph(self.graph, self.layers[0].cfg,
                                   out_dim=self.hidden)
-        layers, plan, x, y_true = self.layers, self.plan, self.x, self.y_true
+        layers = self.layers
+        # the plan's device operands, the features and the labels enter
+        # the step as arguments, not as baked-in constants
+        arrays, rebuild = split_arrays(
+            (self.plan.carrier, self.x, self.y_true))
 
-        def loss_fn(ps, batch):
+        def loss_fn(ps, batch, arrays):
+            carrier, x, y_true = rebuild(arrays)
             nodes = jnp.asarray(batch["nodes"])
             labels = y_true[nodes]
-            logits = apply_stack(layers, ps, plan, x)[nodes]
+            logits = apply_stack(layers, ps, carrier, x)[nodes]
             ll = jax.nn.log_softmax(logits, -1)
             return -jnp.mean(jnp.take_along_axis(ll, labels[:, None], 1))
 
+        self.loss_fn, self.consts = loss_fn, (arrays,)
         self._jit_step = make_gnn_train_step(
             loss_fn, peak_lr=self.peak_lr, warmup=min(20, self.steps),
             total_steps=self.steps)
@@ -103,7 +112,7 @@ class ElasticGNNTrainer:
 
     def step(self, params, opt, batch):
         """Stable train-step callable; delegates to the current jit."""
-        return self._jit_step(params, opt, batch)
+        return self._jit_step(params, opt, batch, *self.consts)
 
     # --------------------------------------------------------- policy
     def remesh(self, num_shards: int):

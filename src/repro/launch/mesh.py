@@ -11,16 +11,20 @@ from __future__ import annotations
 from typing import Optional, Sequence
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str]):
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    """`jax.make_mesh` with every axis `Auto`: jax 0.9 defaults to
+    `Explicit` axes, which `NamedSharding` constraints cannot refer to."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_elastic_mesh(n_devices: Optional[int] = None, model_parallel: int = 16):
@@ -32,8 +36,8 @@ def make_elastic_mesh(n_devices: Optional[int] = None, model_parallel: int = 16)
     while n % mp != 0:
         mp //= 2
     mp = max(mp, 1)
-    return jax.make_mesh((n // mp, mp), ("data", "model"))
+    return make_mesh((n // mp, mp), ("data", "model"))
 
 
 def single_device_mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))

@@ -91,7 +91,7 @@ def build(arch: str, *, smoke: bool, batch: int, seq: int, steps: int,
 def build_gnn(*, model: str, dataset: str, backend: str, steps: int,
               hidden: int = 32, batch: int = 256,
               ring_shards=None, device_budget_bytes=None,
-              max_vertices: int = 4000, max_edges: int = 30_000,
+              max_vertices=None, max_edges=None, feature_dim=None,
               peak_lr: float = 5e-3, seed: int = 0,
               strike_limit: int = 3):
     """Assemble (train_step, init_state, data, graph_dict, aux) for a
@@ -109,7 +109,11 @@ def build_gnn(*, model: str, dataset: str, backend: str, steps: int,
     The returned step is owned by an `ElasticGNNTrainer`
     (`aux["trainer"]`): its `on_failure`/`on_straggler` hooks re-mesh
     the ring to the surviving shard count and re-jit in place
-    (DESIGN.md C13)."""
+    (DESIGN.md C13).
+
+    The graph is the dataset's Table-5 stand-in at its published size
+    and feature width; `max_vertices` / `max_edges` / `feature_dim` cut
+    it down (tests on the CPU pass small sizes)."""
     from repro.core.engn import prepare_graph
     from repro.core.models import apply_stack, init_stack, make_gnn_stack
     from repro.data.pipeline import GraphNodeStream
@@ -118,8 +122,7 @@ def build_gnn(*, model: str, dataset: str, backend: str, steps: int,
     from repro.training.optimizer import init_opt_state
 
     g, f, classes = make_dataset(dataset, max_vertices=max_vertices,
-                                 max_edges=max_edges)
-    f = min(f, 128)
+                                 max_edges=max_edges, feature_dim=feature_dim)
     x = jnp.asarray(random_features(g.num_vertices, f, seed=seed))
     gn = g.gcn_normalized()
 
@@ -241,6 +244,8 @@ def run_gnn(args) -> None:
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS,
                     help="transformer architecture (LM mode)")

@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.nn.config import ModelConfig
 
@@ -138,9 +137,9 @@ def moe_ffn_a2a(cfg: ModelConfig, p, x: jnp.ndarray, mesh: Mesh, rules,
     # train path (seq sharded) keeps the check (and its autodiff psum
     # bookkeeping, verified in tests/test_moe_a2a.py).
     check = bool(seq_axes)
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(r_spec, w_spec, w_spec, w_spec, x_spec),
-                   out_specs=x_spec, check_rep=check)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(r_spec, w_spec, w_spec, w_spec, x_spec),
+                       out_specs=x_spec, check_vma=check)
     out = fn(p["router"], p["w_gate"], p["w_up"], p["w_down"], x)
 
     if cfg.n_shared_experts:

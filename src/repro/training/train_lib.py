@@ -76,12 +76,17 @@ def make_gnn_train_step(loss_fn: Callable, *,
     includes the streamed out-of-core "tiled" backend: its aggregate is
     a custom_vjp host callback whose backward re-streams the transposed
     tile store (core/tiled.py, DESIGN.md C9), so the whole step still
-    jits and grads flow to the parameters."""
+    jits and grads flow to the parameters.
+
+    Extra positional arguments of the step (`step(params, opt, batch,
+    *consts)`) reach `loss_fn(params, batch, *consts)` unchanged: pass
+    device-resident graph operands and features that way rather than
+    closing over them, so they are program parameters, not constants."""
     opt_cfg = opt_cfg if opt_cfg is not None else AdamWConfig(
         weight_decay=0.01)
 
-    def train_step(params, opt_state, batch):
-        loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+    def train_step(params, opt_state, batch, *consts):
+        loss, grads = jax.value_and_grad(loss_fn)(params, batch, *consts)
         grads, gnorm = clip_by_global_norm(grads, opt_cfg.clip_norm)
         lr = cosine_schedule(opt_state["count"] + 1, peak_lr=peak_lr,
                              warmup=warmup, total=total_steps)

@@ -21,7 +21,8 @@ def _build(backend, steps, **kw):
     from repro.launch.train import build_gnn
     return build_gnn(model="gcn", dataset="pubmed", backend=backend,
                      steps=steps, hidden=8, batch=64,
-                     max_vertices=300, max_edges=2000, **kw)
+                     max_vertices=300, max_edges=2000, feature_dim=128,
+                     **kw)
 
 
 def _segment_losses(steps):

@@ -145,6 +145,47 @@ def test_streamed_vjp_matches_fd():
             _check_fd(loss, x, seed=9)
 
 
+@pytest.mark.parametrize("kernel,op", [("spmm", "sum"), ("spmm", "max"),
+                                       ("gather", "sum"),
+                                       ("gather", "max"), ("fused", "sum")])
+def test_kernel_dispatch_grad_is_reference_vjp(kernel, op):
+    """`pallas_call` has no transpose rule: a dispatcher that picks the
+    Pallas kernel (interpret mode here, Mosaic on TPU) differentiates
+    through its custom VJP, which must equal AD of the XLA path."""
+    from repro.graphs.format import coo_to_blocked
+    from repro.kernels.fused_engn.ops import fused_engn_layer
+    from repro.kernels.rer_spmm.ops import blocked_spmm, prepare_blocks
+    g = _float_graph(100, 600, seed=1)
+    t = 32
+    b = coo_to_blocked(g, t, order="column")
+    rng = np.random.default_rng(2)
+    x = jnp.asarray(rng.normal(size=(b.q * t, 8)).astype(np.float32))
+    w = jnp.asarray(rng.normal(size=(8, 4)).astype(np.float32))
+    if kernel == "gather":
+        gr = gather_ops.prepare_packed_groups(
+            pack_tile_store(build_tile_store(g, t)), 8)[-1]
+        graph = (gr.rows, gr.cols, gr.vals, gr.block_row, gr.block_col)
+
+        def agg(xx, impl):
+            return gather_ops.packed_spmm(*graph, xx, q=b.q, op=op,
+                                          impl=impl)
+    else:
+        graph = prepare_blocks(b.blocks, b.block_row, b.block_col, b.q)
+        if kernel == "spmm":
+            def agg(xx, impl):
+                return blocked_spmm(*graph, xx, q=b.q, op=op, impl=impl)
+        else:
+            def agg(xx, impl):
+                return fused_engn_layer(*graph, xx, w, q=b.q, impl=impl)
+
+    def grad(impl):
+        return jax.jit(jax.grad(
+            lambda xx: jnp.sum(agg(xx, impl) ** 2)))(x)
+    np.testing.assert_allclose(np.asarray(grad("pallas")),
+                               np.asarray(grad("xla")),
+                               rtol=1e-5, atol=1e-5)
+
+
 @pytest.mark.skipif(jax.default_backend() == "cpu",
                     reason="Pallas interpret mode is correctness-only "
                            "and has no reverse rules; the kernel grad "
@@ -477,7 +518,8 @@ def test_gnn_training_trajectory_tiled_matches_blocked():
     within 1e-4."""
     from repro.launch.train import build_gnn
     kw = dict(model="gcn", dataset="pubmed", steps=6, hidden=16,
-              batch=64, max_vertices=300, max_edges=2500)
+              batch=64, max_vertices=300, max_edges=2500,
+              feature_dim=128)
     step_b, st_b, data_b, gd_b, _ = build_gnn(backend="blocked",
                                               device_budget_bytes=None,
                                               **kw)

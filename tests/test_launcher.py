@@ -34,7 +34,8 @@ def _gnn_losses(backend, steps=8, **kw):
     from repro.launch.train import build_gnn
     step, state, data, gd, aux = build_gnn(
         model="gcn", dataset="pubmed", backend=backend, steps=steps,
-        hidden=8, batch=64, max_vertices=300, max_edges=2000, **kw)
+        hidden=8, batch=64, max_vertices=300, max_edges=2000,
+        feature_dim=128, **kw)
     losses = []
     ps, opt = state["params"], state["opt"]
     for _ in range(steps):
