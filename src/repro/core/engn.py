@@ -47,6 +47,7 @@ from repro.core.tiled import (DeviceBudgetExceeded, TiledExecutor,
                               make_streamed_aggregate)
 from repro.graphs.format import COOGraph, coo_to_blocked
 from repro.graphs.partition import tile_schedule_order
+from repro.trace import AGGREGATE, EXTRACT, UPDATE, scope
 
 
 AggregateOp = str  # "sum" | "max" | "mean"
@@ -247,22 +248,28 @@ class EnGNLayer:
         if (linear_sum and backend == "fused"
                 and self.dasr_order() == "fau"):
             # Fig. 8 stage overlap: extraction fused into the aggregate
-            # sweep (P = X@W lives only in VMEM per tile)
+            # sweep (P = X@W lives only in VMEM per tile), so the one
+            # kernel is scoped as the aggregate
             from repro.kernels.fused_engn import fused_engn_layer
             n = graph["n"]
             pad_n = graph["blocks_meta"]["padded"]
-            xf = jnp.zeros((pad_n, x.shape[1]), x.dtype).at[:n].set(x)
-            y = fused_engn_layer(graph["blocks"], graph["block_row"],
-                                 graph["block_col"], xf, params["w"],
-                                 q=graph["blocks_meta"]["q"])
-            return self.update(params, x, y[:n])
-        if linear_sum and self.dasr_order() == "afu":
-            ax = agg(x)                                 # (AX)
-            h = self.feature_extraction(params, ax)     # (AX)W
-            return self.update(params, x, h)
-        tmp = self.feature_extraction(params, x)        # XW  (per src vertex)
-        h = agg(tmp)                                    # A(XW)
-        return self.update(params, x, h)
+            with scope(AGGREGATE):
+                xf = jnp.zeros((pad_n, x.shape[1]), x.dtype).at[:n].set(x)
+                y = fused_engn_layer(graph["blocks"], graph["block_row"],
+                                     graph["block_col"], xf, params["w"],
+                                     q=graph["blocks_meta"]["q"])[:n]
+        elif linear_sum and self.dasr_order() == "afu":
+            with scope(AGGREGATE):
+                ax = agg(x)                             # (AX)
+            with scope(EXTRACT):
+                y = self.feature_extraction(params, ax)  # (AX)W
+        else:
+            with scope(EXTRACT):
+                tmp = self.feature_extraction(params, x)  # XW (per src)
+            with scope(AGGREGATE):
+                y = agg(tmp)                            # A(XW)
+        with scope(UPDATE):
+            return self.update(params, x, y)
 
     # -- staged models on every backend (DESIGN.md C10) -------------------
     def _apply_staged(self, params, graph, x, spec) -> jnp.ndarray:
@@ -293,14 +300,8 @@ class EnGNLayer:
         n = graph["n"]
         r = spec["num_relations"]
         h = spec["channels"]
-        if backend == "tiled":
+        if backend == "tiled" and not _is_traced(params, x):
             ex = graph["tiled_exec"]
-            if _is_traced(params, x):
-                from repro.core.tiled import make_streamed_typed_sum
-                agg_fn = make_streamed_typed_sum(ex)
-                xj = jnp.asarray(x, jnp.float32)
-                return self.update(params, xj,
-                                   agg_fn(self.src_payload(params, xj)))
             fns = self._tiled_stage_fns()
             xh = np.asarray(x, np.float32)
             agg = ex.aggregate(xh, "sum", order="auto",
@@ -309,59 +310,73 @@ class EnGNLayer:
                                extract_dim=r * h, out_dim_hint=h,
                                rel_channels=h)
             return ex.stream_map(partial(fns["update"], params), xh, agg)
-        x = jnp.asarray(x, self.cfg.dtype)
+        x = jnp.asarray(x, jnp.float32 if backend == "tiled"
+                        else self.cfg.dtype)
         if backend == "segment":
             src, dst, rel = graph["src"], graph["dst"], graph["rel"]
             val = graph.get("val")
             val = (jnp.ones(src.shape[0], jnp.float32) if val is None
                    else jnp.asarray(val, jnp.float32))
             if spec.get("normalize") and not graph.get("rel_normed"):
-                key = dst * r + rel
-                cnt = jax.ops.segment_sum(jnp.ones_like(val), key,
-                                          num_segments=n * r)
-                val = val / jnp.maximum(cnt[key], 1.0)
+                with scope(AGGREGATE):
+                    key = dst * r + rel
+                    cnt = jax.ops.segment_sum(jnp.ones_like(val), key,
+                                              num_segments=n * r)
+                    val = val / jnp.maximum(cnt[key], 1.0)
             if self.dasr_order() == "afu":
                 # aggregate per (dst, rel) first, then one batched
                 # projection — Eq. 7's cheaper order when F < H
-                ev = x[src] * val[:, None]
-                agg_r = jax.ops.segment_sum(ev, dst * r + rel,
-                                            num_segments=n * r)
-                agg = jnp.einsum("nrf,rfh->nh",
-                                 agg_r.reshape(n, r, x.shape[1]),
-                                 params["wr"])
+                with scope(AGGREGATE):
+                    ev = x[src] * val[:, None]
+                    agg_r = jax.ops.segment_sum(ev, dst * r + rel,
+                                                num_segments=n * r)
+                with scope(EXTRACT):
+                    agg = jnp.einsum("nrf,rfh->nh",
+                                     agg_r.reshape(n, r, x.shape[1]),
+                                     params["wr"])
             else:
-                ev = self.extract(params, x[src], x[dst], val, rel)
-                agg = jax.ops.segment_sum(ev, dst, num_segments=n)
+                with scope(EXTRACT):
+                    ev = self.extract(params, x[src], x[dst], val, rel)
+                with scope(AGGREGATE):
+                    agg = jax.ops.segment_sum(ev, dst, num_segments=n)
+        elif backend in ("tiled", "blocked", "ring"):
+            with scope(EXTRACT):
+                xw = self.src_payload(params, x)          # (n, r*h)
+            with scope(AGGREGATE):
+                agg = self._typed_sum(graph, xw, backend, n, r, h, x.dtype)
+        else:
+            raise ValueError(backend)
+        with scope(UPDATE):
             return self.update(params, x, agg)
-        if backend == "blocked":
-            xw = self.src_payload(params, x)              # (n, r*h)
-            if "typed_flat" in graph:
-                gsrc, gdst, gval, grel = graph["typed_flat"]
-                ev = gval[:, None] * xw.reshape(n * r, h)[gsrc * r + grel]
-                agg = jax.ops.segment_sum(ev, gdst, num_segments=n)
-            else:
-                from repro.kernels.rer_spmm import ops as spmm_ops
-                pad_n = graph["blocks_meta"]["padded"]
-                xf = jnp.zeros((pad_n, r * h), x.dtype).at[:n].set(xw)
-                y = None
-                for blk in graph["typed_blocks"]:
-                    rr = blk["rel"]
-                    part = spmm_ops.blocked_spmm(
-                        blk["blocks"], blk["block_row"], blk["block_col"],
-                        xf[:, rr * h:(rr + 1) * h],
-                        q=blk["q"], op="sum")
-                    y = part if y is None else y + part
-                agg = (y[:n] if y is not None
-                       else jnp.zeros((n, h), x.dtype))
-            return self.update(params, x, agg)
+
+    @staticmethod
+    def _typed_sum(graph, xw, backend, n, r, h, dtype):
+        """The typed aggregate of the stacked payload `xw` (n, r*h); the
+        blocked kernels run at the layer's `dtype`."""
+        if backend == "tiled":
+            from repro.core.tiled import make_streamed_typed_sum
+            return make_streamed_typed_sum(graph["tiled_exec"])(xw)
         if backend == "ring":
             pad_n = graph["ring_meta"]["padded"]
-            xw = self.src_payload(params, x)
             xf = jnp.zeros((pad_n, r * h), jnp.float32).at[:n].set(xw)
             y = graph["ring_fn"](*graph["ring_operands"], xf,
                                  graph["ring_counts"])
-            return self.update(params, x, y[:n])
-        raise ValueError(backend)
+            return y[:n]
+        if "typed_flat" in graph:
+            gsrc, gdst, gval, grel = graph["typed_flat"]
+            ev = gval[:, None] * xw.reshape(n * r, h)[gsrc * r + grel]
+            return jax.ops.segment_sum(ev, gdst, num_segments=n)
+        from repro.kernels.rer_spmm import ops as spmm_ops
+        pad_n = graph["blocks_meta"]["padded"]
+        xf = jnp.zeros((pad_n, r * h), dtype).at[:n].set(xw)
+        y = None
+        for blk in graph["typed_blocks"]:
+            rr = blk["rel"]
+            part = spmm_ops.blocked_spmm(
+                blk["blocks"], blk["block_row"], blk["block_col"],
+                xf[:, rr * h:(rr + 1) * h], q=blk["q"], op="sum")
+            y = part if y is None else y + part
+        return y[:n] if y is not None else jnp.zeros((n, h), dtype)
 
     def _staged_gated(self, params, graph, x, backend):
         """Dst+src sigmoid-gated messages (Gated-GCN, Eq. 4) on every
@@ -371,79 +386,79 @@ class EnGNLayer:
         destination side (tiled) or the stationary shard (ring), pc and
         x on the streamed/rotating source side."""
         n = graph["n"]
-        if backend == "tiled":
+        if backend == "tiled" and not _is_traced(params, x):
             ex = graph["tiled_exec"]
-            if _is_traced(params, x):
-                from repro.core.tiled import make_streamed_gated
-                gated = make_streamed_gated(ex)
-                xj = jnp.asarray(x, jnp.float32)
-                agg = gated(self.gate_dst(params, xj),
-                            self.gate_src(params, xj), xj)
-                return self.update(params, xj, agg)
             fns = self._tiled_stage_fns()
             xh = np.asarray(x, np.float32)
             ph = ex.stream_map(partial(fns["gate_dst"], params), xh)
             pc = ex.stream_map(partial(fns["gate_src"], params), xh)
             agg = ex.gated_aggregate(ph, pc, xh)
             return ex.stream_map(partial(fns["update"], params), xh, agg)
-        x = jnp.asarray(x, self.cfg.dtype)
-        ph = self.gate_dst(params, x)
-        pc = self.gate_src(params, x)
+        x = jnp.asarray(x, jnp.float32 if backend == "tiled"
+                        else self.cfg.dtype)
         if backend == "segment":
             src, dst = graph["src"], graph["dst"]
             val = graph.get("val")
             val = (jnp.ones(src.shape[0], jnp.float32) if val is None
                    else jnp.asarray(val, jnp.float32))
-            ev = self.extract(params, x[src], x[dst], val, None)
-            agg = jax.ops.segment_sum(ev, dst, num_segments=n)
+            with scope(EXTRACT):
+                ev = self.extract(params, x[src], x[dst], val, None)
+            with scope(AGGREGATE):
+                agg = jax.ops.segment_sum(ev, dst, num_segments=n)
+        elif backend in ("tiled", "blocked", "ring"):
+            with scope(EXTRACT):
+                ph = self.gate_dst(params, x)
+                pc = self.gate_src(params, x)
+            with scope(AGGREGATE):
+                agg = self._gated_sum(graph, ph, pc, x, backend, n)
+        else:
+            raise ValueError(backend)
+        with scope(UPDATE):
             return self.update(params, x, agg)
-        if backend == "blocked":
-            meta = graph["blocks_meta"]
-            pad_n = meta["padded"]
 
-            def pad(a):
-                return jnp.zeros((pad_n, a.shape[1]),
-                                 jnp.float32).at[:n].set(a)
-            if "packed_flat" in graph:
-                gsrc, gdst, gval = graph["packed_flat"]
-                xf, phf, pcf = pad(x), pad(ph), pad(pc)
-                z = jax.nn.sigmoid(phf[gdst] + pcf[gsrc])
-                ev = gval[:, None] * z * xf[gsrc]
-                agg = jax.ops.segment_sum(ev, gdst,
-                                          num_segments=pad_n)[:n]
-            elif "packed_groups" in graph:
-                raise ValueError(
-                    "the gated contract needs the flat packed carrier "
-                    "(XLA gather); the Mosaic bucket-group layout does "
-                    "not carry endpoint projections — use "
-                    "tile_format='dense' on TPU")
-            else:
-                q, t = meta["q"], meta["tile"]
-                blocks = graph["blocks"]
-                brow, bcol = graph["block_row"], graph["block_col"]
-                xt = pad(x).reshape(q, t, -1)
-                pht = pad(ph).reshape(q, t, -1)
-                pct = pad(pc).reshape(q, t, -1)
-                z = jax.nn.sigmoid(pht[brow][:, :, None, :]
-                                   + pct[bcol][:, None, :, :])
-                contrib = jnp.where(
-                    blocks[..., None] != 0.0,
-                    blocks[..., None] * z * xt[bcol][:, None, :, :], 0.0)
-                part = jnp.sum(contrib, axis=2)       # (nnzb, t, f)
-                agg = jax.ops.segment_sum(
-                    part, brow, num_segments=q).reshape(pad_n, -1)[:n]
-            return self.update(params, x, agg)
+    @staticmethod
+    def _gated_sum(graph, ph, pc, x, backend, n):
+        """The gated aggregate of x under the endpoint projections."""
+        if backend == "tiled":
+            from repro.core.tiled import make_streamed_gated
+            return make_streamed_gated(graph["tiled_exec"])(ph, pc, x)
+        pad_n = graph["ring_meta" if backend == "ring"
+                     else "blocks_meta"]["padded"]
+
+        def pad(a):
+            return jnp.zeros((pad_n, a.shape[1]), jnp.float32).at[:n].set(a)
         if backend == "ring":
-            pad_n = graph["ring_meta"]["padded"]
-
-            def pad(a):
-                return jnp.zeros((pad_n, a.shape[1]),
-                                 jnp.float32).at[:n].set(a)
             pcx = jnp.concatenate([pad(pc), pad(x)], axis=1)
             y = graph["ring_fn"](*graph["ring_operands"], pad(ph), pcx,
                                  graph["ring_counts"])
-            return self.update(params, x, y[:n])
-        raise ValueError(backend)
+            return y[:n]
+        if "packed_flat" in graph:
+            gsrc, gdst, gval = graph["packed_flat"]
+            xf, phf, pcf = pad(x), pad(ph), pad(pc)
+            z = jax.nn.sigmoid(phf[gdst] + pcf[gsrc])
+            ev = gval[:, None] * z * xf[gsrc]
+            return jax.ops.segment_sum(ev, gdst, num_segments=pad_n)[:n]
+        if "packed_groups" in graph:
+            raise ValueError(
+                "the gated contract needs the flat packed carrier "
+                "(XLA gather); the Mosaic bucket-group layout does "
+                "not carry endpoint projections — use "
+                "tile_format='dense' on TPU")
+        meta = graph["blocks_meta"]
+        q, t = meta["q"], meta["tile"]
+        blocks = graph["blocks"]
+        brow, bcol = graph["block_row"], graph["block_col"]
+        xt = pad(x).reshape(q, t, -1)
+        pht = pad(ph).reshape(q, t, -1)
+        pct = pad(pc).reshape(q, t, -1)
+        z = jax.nn.sigmoid(pht[brow][:, :, None, :]
+                           + pct[bcol][:, None, :, :])
+        contrib = jnp.where(
+            blocks[..., None] != 0.0,
+            blocks[..., None] * z * xt[bcol][:, None, :, :], 0.0)
+        part = jnp.sum(contrib, axis=2)               # (nnzb, t, f)
+        return jax.ops.segment_sum(
+            part, brow, num_segments=q).reshape(pad_n, -1)[:n]
 
     # -- streamed out-of-core path, differentiable (DESIGN.md C9) ---------
     def _apply_tiled_diff(self, params, graph, x) -> jnp.ndarray:
@@ -462,11 +477,17 @@ class EnGNLayer:
                       and type(self).feature_extraction
                       is EnGNLayer.feature_extraction)
         if linear_sum and self.dasr_order() == "afu":
-            ax = agg(x)                                  # (AX)
-            return self.update(params, x,
-                               self.feature_extraction(params, ax))
-        tmp = self.feature_extraction(params, x)         # XW
-        return self.update(params, x, agg(tmp))          # A(XW)
+            with scope(AGGREGATE):
+                ax = agg(x)                              # (AX)
+            with scope(EXTRACT):
+                y = self.feature_extraction(params, ax)
+        else:
+            with scope(EXTRACT):
+                tmp = self.feature_extraction(params, x)  # XW
+            with scope(AGGREGATE):
+                y = agg(tmp)                             # A(XW)
+        with scope(UPDATE):
+            return self.update(params, x, y)
 
     # -- streamed out-of-core path (core/tiled.py, DESIGN.md C7) ----------
     def _tiled_stage_fns(self):

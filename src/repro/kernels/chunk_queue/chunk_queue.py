@@ -151,6 +151,7 @@ def chunk_queue_spmm(tile_ptr: jnp.ndarray, tile_src: jnp.ndarray,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((q_dst * t, f_pad), jnp.float32),
+        name="chunk_queue",
         interpret=interpret,
     )(tile_ptr, tile_src, rows, cols, vals, x)
     return y[:, :f]

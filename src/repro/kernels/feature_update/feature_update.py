@@ -55,5 +55,6 @@ def fused_linear_act_kernel(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((tn, th), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n, h), jnp.float32),
+        name="feature_update",
         interpret=interpret,
     )(x, w, b)

@@ -70,5 +70,6 @@ def fused_extract_aggregate(blocks: jnp.ndarray, block_row: jnp.ndarray,
             out_specs=pl.BlockSpec((t, hc), lambda j, k, br, bc: (br[k], j)),
         ),
         out_shape=jax.ShapeDtypeStruct((n_pad, h), jnp.float32),
+        name="fused_engn",
         interpret=interpret,
     )(block_row, block_col, blocks, x, w)

@@ -160,6 +160,7 @@ def rer_gather(rows: jnp.ndarray, cols: jnp.ndarray, vals: jnp.ndarray,
                                    lambda j, k, br, bc: (br[k], j)),
         ),
         out_shape=jax.ShapeDtypeStruct((q_dst * t, f), jnp.float32),
+        name="rer_gather_packed_spmm",
         interpret=interpret,
     )(block_row, block_col, rows, cols, vals, x)
     if op == "max" and finish_max:

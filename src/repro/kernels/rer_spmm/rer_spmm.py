@@ -83,6 +83,7 @@ def rer_spmm(blocks: jnp.ndarray, block_row: jnp.ndarray,
             out_specs=pl.BlockSpec((t, fc), lambda j, k, br, bc: (br[k], j)),
         ),
         out_shape=jax.ShapeDtypeStruct((n_pad, f), jnp.float32),
+        name="rer_spmm_blocked_spmm",
         interpret=interpret,
     )(block_row, block_col, blocks, x)
     if op == "max":

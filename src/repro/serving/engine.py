@@ -52,6 +52,8 @@ from repro.graphs.format import COOGraph
 from repro.graphs.subgraph import SubgraphExtractor
 from repro.serving.batcher import GNNBatcher, Request, Response
 from repro.serving.cache import DegreeAwareCache
+from repro.trace import (SERVE_EXTRACT, SERVE_FINISH, SERVE_GATHER,
+                         SERVE_INFER, SERVE_PAD, SERVE_PROBE, span)
 
 @dataclasses.dataclass
 class ServingConfig:
@@ -179,11 +181,18 @@ class GNNServingEngine:
         self._can_bucket = config.bucketing and all(
             ly.cfg.aggregate_op == "sum" for ly in layers)
         self._compiled: Dict = {}
+        # padded_vertices: rows the device ran, summed over the
+        # device_batches (a bucketed batch runs its whole bucket), so
+        # subgraph_vertices / padded_vertices is the buckets' fill
         self.stats = {"subgraphs": 0, "subgraph_vertices": 0,
-                      "subgraph_edges": 0, "compiles": 0,
-                      "tiled_batches": 0, "ring_batches": 0,
-                      "warm_filled": 0}
+                      "device_batches": 0, "padded_vertices": 0,
+                      "compiles": 0, "tiled_batches": 0,
+                      "ring_batches": 0, "warm_filled": 0}
         self._compat = None           # lazy inline pipeline for step/drain
+        # the pipeline ticket whose device stage runs next, for the spans
+        # of `_infer_batch` (which keeps its (sub, xs) signature for the
+        # wrappers of that stage); -1 outside the pipeline
+        self.ticket = -1
         if config.warm_cache:
             self.warm_fill(config.warm_cache_max)
 
@@ -317,38 +326,43 @@ class GNNServingEngine:
     # -- pipeline stage functions (DESIGN.md C12) --------------------------
     # The async pipeline drives these directly: probe and finish touch the
     # cache and MUST stay on the completion thread; extract is pure numpy
-    # over read-only CSR state and is safe to run on pool workers.
-    def _probe_batch(self, ids: np.ndarray):
+    # over read-only CSR state and is safe to run on pool workers.  Each
+    # stage opens its span on the thread that runs it, with `batch` the
+    # pipeline's ticket number (-1 outside the pipeline).
+    def _probe_batch(self, ids: np.ndarray, batch: int = -1):
         """Cache-probe stage: split a batch into hits and the miss set."""
-        ids = np.asarray(ids, np.int32)
-        if self.cache is not None:
-            mask, out = self.cache.lookup(ids)
-        else:
-            mask, out = np.zeros(ids.size, bool), None
-        miss = np.unique(ids[~mask])
+        with span(SERVE_PROBE, batch=batch):
+            ids = np.asarray(ids, np.int32)
+            if self.cache is not None:
+                mask, out = self.cache.lookup(ids)
+            else:
+                mask, out = np.zeros(ids.size, bool), None
+            miss = np.unique(ids[~mask])
         return ids, mask, out, miss
 
-    def _extract_batch(self, miss: np.ndarray):
+    def _extract_batch(self, miss: np.ndarray, batch: int = -1):
         """Extraction stage (thread-safe, host-side): L-hop subgraph of
         the miss set plus its gathered input features."""
-        sub = self.extractor.extract(miss, self.num_hops,
-                                     self.config.fanout)
-        xs = self.x[sub.vertices]
-        g = sub.graph
+        with span(SERVE_EXTRACT, batch=batch):
+            sub = self.extractor.extract(miss, self.num_hops,
+                                         self.config.fanout)
+        with span(SERVE_GATHER, batch=batch):
+            xs = self.x[sub.vertices]
         self.stats["subgraphs"] += 1
-        self.stats["subgraph_vertices"] += g.num_vertices
-        self.stats["subgraph_edges"] += g.num_edges
+        self.stats["subgraph_vertices"] += sub.graph.num_vertices
         return sub, xs
 
-    def _finish_batch(self, ids, mask, out, miss, y) -> np.ndarray:
+    def _finish_batch(self, ids, mask, out, miss, y,
+                      batch: int = -1) -> np.ndarray:
         """Completion stage: insert fresh rows into the cache and scatter
         hits + misses back into batch order."""
-        if self.cache is not None and miss.size:
-            self.cache.insert(miss, y)
-        if out is None:
-            out = np.zeros((ids.size, y.shape[1]), np.float32)
-        rows = ~mask
-        out[rows] = y[np.searchsorted(miss, ids[rows])]
+        with span(SERVE_FINISH, batch=batch):
+            if self.cache is not None and miss.size:
+                self.cache.insert(miss, y)
+            if out is None:
+                out = np.zeros((ids.size, y.shape[1]), np.float32)
+            rows = ~mask
+            out[rows] = y[np.searchsorted(miss, ids[rows])]
         return out
 
     # -- inference path (called by the batcher, one batch at a time) -------
@@ -367,23 +381,29 @@ class GNNServingEngine:
         """Inference stage (device-side): run the stack over one
         extracted subgraph, routing over-budget batches through the
         ring / streamed-tiled fallbacks."""
-        g = sub.graph
+        g, batch = sub.graph, self.ticket
+        self.stats["device_batches"] += 1
         budget = self.config.engn.device_budget_bytes
         if budget and self._subgraph_footprint(g) > budget:
-            ring_gd = self._try_ring_plan(g)
-            if ring_gd is not None:
-                return self._run_subgraph_ring(sub, xs, ring_gd)
-            return self._run_subgraph_tiled(sub, xs)
+            self.stats["padded_vertices"] += g.num_vertices
+            with span(SERVE_INFER, batch=batch):
+                ring_gd = self._try_ring_plan(g)
+                if ring_gd is not None:
+                    return self._run_subgraph_ring(sub, xs, ring_gd)
+                return self._run_subgraph_tiled(sub, xs)
         if not self._can_bucket:
-            gd = {"n": g.num_vertices, "src": jnp.asarray(g.src),
-                  "dst": jnp.asarray(g.dst), "val": jnp.asarray(g.weights())}
-            if g.rel is not None:
-                gd["rel"] = jnp.asarray(g.rel)
-                gd["num_relations"] = g.num_relations
-            y = xs
-            for layer, p in zip(self.layers, self.params):
-                y = layer.apply(p, gd, jnp.asarray(y))
-            return np.asarray(y[:sub.num_seeds])
+            self.stats["padded_vertices"] += g.num_vertices
+            with span(SERVE_INFER, batch=batch):
+                gd = {"n": g.num_vertices, "src": jnp.asarray(g.src),
+                      "dst": jnp.asarray(g.dst),
+                      "val": jnp.asarray(g.weights())}
+                if g.rel is not None:
+                    gd["rel"] = jnp.asarray(g.rel)
+                    gd["num_relations"] = g.num_relations
+                y = xs
+                for layer, p in zip(self.layers, self.params):
+                    y = layer.apply(p, gd, jnp.asarray(y))
+                return np.asarray(y[:sub.num_seeds])
 
         # pow2-bucketed shapes, best-fit reuse: prefer the smallest
         # already-compiled bucket that fits (padded compute is cheaper
@@ -397,22 +417,25 @@ class GNNServingEngine:
         else:
             n_pad = max(_next_pow2(n_need), 256)
             e_pad = max(_next_pow2(e_need), 1024)
-        dummy = n_pad - 1
-        src = np.full(e_pad, dummy, np.int32)
-        dst = np.full(e_pad, dummy, np.int32)
-        val = np.zeros(e_pad, np.float32)        # padding edges weigh 0
-        src[:g.num_edges] = g.src
-        dst[:g.num_edges] = g.dst
-        val[:g.num_edges] = g.weights()
-        rel = None
-        if g.rel is not None:
-            # padding edges are rel 0 at the dummy vertex: with weight 0
-            # they add nothing, and the typed in-trace normalisation only
-            # pollutes the dummy row the slice below discards
-            rel = np.zeros(e_pad, np.int32)
-            rel[:g.num_edges] = g.rel
-        xf = np.zeros((n_pad, xs.shape[1]), np.float32)
-        xf[:xs.shape[0]] = xs
+        self.stats["padded_vertices"] += n_pad
+        with span(SERVE_PAD, batch=batch):
+            dummy = n_pad - 1
+            src = np.full(e_pad, dummy, np.int32)
+            dst = np.full(e_pad, dummy, np.int32)
+            val = np.zeros(e_pad, np.float32)    # padding edges weigh 0
+            src[:g.num_edges] = g.src
+            dst[:g.num_edges] = g.dst
+            val[:g.num_edges] = g.weights()
+            rel = None
+            if g.rel is not None:
+                # padding edges are rel 0 at the dummy vertex: with
+                # weight 0 they add nothing, and the typed in-trace
+                # normalisation only pollutes the dummy row the slice
+                # below discards
+                rel = np.zeros(e_pad, np.int32)
+                rel[:g.num_edges] = g.rel
+            xf = np.zeros((n_pad, xs.shape[1]), np.float32)
+            xf[:xs.shape[0]] = xs
 
         key = (n_pad, e_pad)
         fn = self._compiled.get(key)
@@ -420,10 +443,11 @@ class GNNServingEngine:
             fn = jax.jit(partial(self._stack_fn, n_pad))
             self._compiled[key] = fn
             self.stats["compiles"] += 1
-        y = np.asarray(fn(jnp.asarray(src), jnp.asarray(dst),
-                          jnp.asarray(val),
-                          jnp.asarray(rel) if rel is not None else None,
-                          jnp.asarray(xf)))
+        with span(SERVE_INFER, batch=batch):
+            y = np.asarray(fn(jnp.asarray(src), jnp.asarray(dst),
+                              jnp.asarray(val),
+                              jnp.asarray(rel) if rel is not None else None,
+                              jnp.asarray(xf)))
         return y[:sub.num_seeds]
 
     def _stack_fn(self, n_pad, src, dst, val, rel, xf):

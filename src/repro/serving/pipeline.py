@@ -62,7 +62,9 @@ class EngineFailure(RuntimeError):
 @dataclass
 class _Ticket:
     """One in-flight batch: the frozen admission record plus the probe
-    result and the (possibly async) extraction handle."""
+    result and the (possibly async) extraction handle.  `seq` numbers
+    the tickets; the stages' spans carry it as `batch`."""
+    seq: int
     batch: AdmittedBatch
     ids: np.ndarray
     mask: np.ndarray
@@ -115,10 +117,16 @@ class ServingPipeline:
                               else default_slo_s)
         self.inflight: Deque[_Ticket] = deque()
         self._ewma_s_per_vertex: Optional[float] = None
-        self.stats: Dict[str, int] = {"pumped_batches": 0,
-                                      "adaptive_merges": 0,
-                                      "inflight_hwm": 0,
-                                      "batch_errors": 0}
+        self._seq = 0
+        # hit_batches: tickets with an empty miss set; hit_batch_wait_s:
+        # their seconds from admission to completion, which FIFO
+        # completion spends behind the miss batches ahead of them
+        self.stats: Dict[str, float] = {"pumped_batches": 0,
+                                        "adaptive_merges": 0,
+                                        "inflight_hwm": 0,
+                                        "batch_errors": 0,
+                                        "hit_batches": 0,
+                                        "hit_batch_wait_s": 0.0}
 
     # -- submission --------------------------------------------------------
     def submit(self, rid: int, vertex_ids: np.ndarray,
@@ -175,15 +183,17 @@ class ServingPipeline:
             batch = self.batcher.admit(now, force=force, budget=budget)
             if batch is None:
                 break
-            ids, mask, out, miss = self.engine._probe_batch(batch.batch_ids)
-            t = _Ticket(batch, ids, mask, out, miss, t_admit=now)
+            seq, self._seq = self._seq, self._seq + 1
+            ids, mask, out, miss = self.engine._probe_batch(
+                batch.batch_ids, batch=seq)
+            t = _Ticket(seq, batch, ids, mask, out, miss, t_admit=now)
             if miss.size:
                 if self.pool is not None:
                     t.future = self.pool.submit(
-                        self.engine._extract_batch, miss)
+                        self.engine._extract_batch, miss, seq)
                 else:
                     try:
-                        t.extracted = self.engine._extract_batch(miss)
+                        t.extracted = self.engine._extract_batch(miss, seq)
                     except EngineFailure:
                         raise
                     except Exception:  # noqa: BLE001 — per-request error
@@ -204,9 +214,11 @@ class ServingPipeline:
             if t.miss.size:
                 sub, xs = (t.future.result() if t.future is not None
                            else t.extracted)
+                self.engine.ticket = t.seq
                 y = self.engine._infer_batch(sub, xs)
+                self.engine.ticket = -1
                 out = self.engine._finish_batch(t.ids, t.mask, t.out,
-                                                t.miss, y)
+                                                t.miss, y, t.seq)
             else:
                 out = t.out
         except EngineFailure:
@@ -219,6 +231,9 @@ class ServingPipeline:
             return self.batcher.fail(t.batch, time.monotonic())
         now = time.monotonic()
         self._observe(t.batch, now - t.t_admit)
+        if not t.miss.size:
+            self.stats["hit_batches"] += 1
+            self.stats["hit_batch_wait_s"] += now - t.t_admit
         if t.batch.ids.size:
             out = out[t.batch.inv]
         else:

@@ -11,6 +11,7 @@ import jax.numpy as jnp
 
 from repro.nn.config import ModelConfig
 from repro.nn import transformer as T
+from repro.trace import OPTIMIZER, scope
 from repro.training.optimizer import (AdamWConfig, adamw_update,
                                       clip_by_global_norm)
 from repro.training.schedule import cosine_schedule, wsd_schedule
@@ -87,11 +88,12 @@ def make_gnn_train_step(loss_fn: Callable, *,
 
     def train_step(params, opt_state, batch, *consts):
         loss, grads = jax.value_and_grad(loss_fn)(params, batch, *consts)
-        grads, gnorm = clip_by_global_norm(grads, opt_cfg.clip_norm)
-        lr = cosine_schedule(opt_state["count"] + 1, peak_lr=peak_lr,
-                             warmup=warmup, total=total_steps)
-        params, opt_state = adamw_update(opt_cfg, grads, opt_state,
-                                         params, lr)
+        with scope(OPTIMIZER):
+            grads, gnorm = clip_by_global_norm(grads, opt_cfg.clip_norm)
+            lr = cosine_schedule(opt_state["count"] + 1, peak_lr=peak_lr,
+                                 warmup=warmup, total=total_steps)
+            params, opt_state = adamw_update(opt_cfg, grads, opt_state,
+                                             params, lr)
         return params, opt_state, {"loss": loss, "grad_norm": gnorm,
                                    "lr": lr}
 
