@@ -167,6 +167,11 @@ class EnGNLayer:
         """Default: ReLU activation."""
         return jax.nn.relu(agg)
 
+    def update_reads_self(self) -> bool:
+        """Whether `update` reads `x_self` beside the aggregate: not the
+        default's; assumed of any override that does not say otherwise."""
+        return type(self).update is not EnGNLayer.update
+
     # -- stage contract (DESIGN.md C10) -----------------------------------
     def stage_spec(self) -> Optional[Dict[str, Any]]:
         """The model's per-stage contract, or None for the default
@@ -266,8 +271,21 @@ class EnGNLayer:
         else:
             with scope(EXTRACT):
                 tmp = self.feature_extraction(params, x)  # XW (per src)
-            with scope(AGGREGATE):
-                y = agg(tmp)                            # A(XW)
+            return self.aggregate_update(params, graph, x, tmp, agg)
+        with scope(UPDATE):
+            return self.update(params, x, y)
+
+    def aggregate_update(self, params, graph, x, h,
+                         aggregate_fn: Optional[Callable] = None
+                         ) -> jnp.ndarray:
+        """The stages after extraction first: aggregate the extracted
+        `h` (A(XW)) and update.  `x` is the layer's input, which `update`
+        reads as `x_self`; it may be None where `update_reads_self()` is
+        False (the serving engine extracts from rows it never holds
+        whole)."""
+        agg = aggregate_fn or partial(self._aggregate, plan_carrier(graph))
+        with scope(AGGREGATE):
+            y = agg(h)                                  # A(XW)
         with scope(UPDATE):
             return self.update(params, x, y)
 

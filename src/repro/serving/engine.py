@@ -28,6 +28,17 @@ in a large fraction of the graph) is executed through the streamed
 tiled executor instead of OOMing — same results, bounded device
 footprint, counted in `stats["tiled_batches"]`.
 
+Resident features: without a `device_budget_bytes`, when layer 0 can
+reduce its input row block by row block (a default-contract sum layer,
+extraction first, whose update reads only the aggregate: GCN), and when
+x takes at most `RESIDENT_SHARE` of the device's memory, the engine
+puts `x` on the device once and each bucket program gathers its batch's
+rows there, a block of rows at a time, straight into layer 0's
+extraction.  A batch then sends only its edges and its vertex ids, and
+`engn.serve.gather` does not run.  Other stacks, budgeted (out-of-core)
+engines and larger features gather, pad and send the rows from the host
+on every batch.
+
 Shard-aware gate (DESIGN.md C2): with `ring_shards` additionally set,
 an over-budget batch first tries the sharded ring-tiled backend — the
 budget is per *shard*, so a P-device ring holds a P-times-larger
@@ -45,6 +56,7 @@ from typing import Any, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro.core.engn import EnGNConfig
 from repro.core.tiled import TiledExecutor, dense_footprint_bytes
@@ -52,8 +64,16 @@ from repro.graphs.format import COOGraph
 from repro.graphs.subgraph import SubgraphExtractor
 from repro.serving.batcher import GNNBatcher, Request, Response
 from repro.serving.cache import DegreeAwareCache
-from repro.trace import (SERVE_EXTRACT, SERVE_FINISH, SERVE_GATHER,
-                         SERVE_INFER, SERVE_PAD, SERVE_PROBE, span)
+from repro.trace import (EXTRACT, SERVE_EXTRACT, SERVE_FINISH,
+                         SERVE_GATHER, SERVE_INFER, SERVE_PAD, SERVE_PROBE,
+                         scope, span)
+
+# device bytes of one gathered block of input rows: the bucket program
+# never holds more of the (n_pad, F) input than this at once
+BLOCK_BYTES = 256 << 20
+# share of the device's memory the resident feature matrix may take; a
+# larger one stays on the host and each batch's rows go over
+RESIDENT_SHARE = 0.5
 
 @dataclasses.dataclass
 class ServingConfig:
@@ -136,20 +156,86 @@ def _next_pow2(n: int) -> int:
     return 1 << max(int(n) - 1, 0).bit_length()
 
 
+def block_rows(n_pad: int, feat_dim: int) -> int:
+    """Rows of one gathered block: the largest power of two whose
+    float32 rows fit `BLOCK_BYTES`, at most `n_pad`."""
+    rows = max(BLOCK_BYTES // (4 * feat_dim), 1)
+    return min(1 << (rows.bit_length() - 1), n_pad)
+
+
+def gather_extract(extract, x: jnp.ndarray, vids: jnp.ndarray,
+                   rows: int) -> jnp.ndarray:
+    """`extract(xf)` for the (len(vids), F) input `xf` whose rows are
+    `x[vids]` where `vids` holds an id and zero where it holds -1 (the
+    padding, after every id), made `rows` rows at a time so `xf` never
+    exists whole.  `rows` divides len(vids); blocks of padding alone are
+    not gathered: their rows are `extract` of a zero row."""
+    n = vids.shape[0]
+    zero = extract(jnp.zeros((1, x.shape[1]), x.dtype))
+    out = jnp.broadcast_to(zero, (n, zero.shape[1]))
+
+    def block(b, out):
+        ids = lax.dynamic_slice_in_dim(vids, b * rows, rows)
+        xb = jnp.where((ids >= 0)[:, None], x[jnp.maximum(ids, 0)], 0)
+        return lax.dynamic_update_slice_in_dim(out, extract(xb), b * rows,
+                                               0)
+    n_real = jnp.sum(vids >= 0)
+    return lax.fori_loop(0, (n_real + rows - 1) // rows, block, out)
+
+
+def device_bytes_limit() -> Optional[int]:
+    """Bytes the default device can allocate, or None where it does not
+    say (a CPU)."""
+    stats = jax.local_devices()[0].memory_stats() or {}
+    return stats.get("bytes_limit")
+
+
+def _to_device_f32(x: np.ndarray) -> Optional[jax.Array]:
+    """`x` as float32 on the device, padded with zero columns to a
+    multiple of 128, or None where that takes more than `RESIDENT_SHARE`
+    of the device's memory.  A TPU lays a matrix out in whichever order
+    pads it less, and NELL's 65,755 x 5,415 pads less column-major; a
+    row gather from that copies all of x first, in every program
+    (4.3 ms and 1.4 GB a batch on a v5e).  With whole 128-lane rows
+    row-major pads less, and the gather reads rows in place."""
+    n, f = x.shape
+    limit = device_bytes_limit()
+    if limit is not None and n * (f + -f % 128) * 4 > RESIDENT_SHARE * limit:
+        return None
+    x = np.asarray(x, np.float32)
+    return jax.device_put(np.pad(x, ((0, 0), (0, -f % 128))))
+
+
+def _gathers_on_device(layer) -> bool:
+    """Whether `layer`, as layer 0 of a sum stack, can take its input
+    through `gather_extract`: default stage contract, extraction first,
+    and an update that reads the aggregate alone."""
+    return (layer.stage_spec() is None and layer.dasr_order() == "fau"
+            and not layer.update_reads_self())
+
+
 class GNNServingEngine:
     """Serve vertex-embedding requests over a (normalised) graph.
 
     graph:  the full COOGraph, already normalised for the model (e.g.
             `gcn_normalized()` for GCN stacks).
-    x:      (N, F) input features (host array; rows are gathered per
-            subgraph).
+    x:      (N, F) input features, a host array.  Where the stack
+            gathers on the device (module docstring) and the copy takes
+            at most `RESIDENT_SHARE` of the device's memory, a float32
+            copy, its rows padded with zeros to a multiple of 128, is
+            kept on the device as `x_device` and each batch's rows are
+            gathered there; otherwise each batch's rows are gathered
+            from `x` on the host.
     layers/params: an EnGN stack from `core.models.make_gnn_stack` /
             `init_stack`, segment backend.
+    x_device: an existing device copy of `x` to share (ReplicatedServer
+            runs N engines over one); made from `x` when None.
     """
 
     def __init__(self, graph: COOGraph, x: np.ndarray, layers, params,
                  config: Optional[ServingConfig] = None,
-                 extractor: Optional[SubgraphExtractor] = None):
+                 extractor: Optional[SubgraphExtractor] = None,
+                 x_device: Optional[jax.Array] = None):
         config = config if config is not None else ServingConfig()
         bad = [ly.name for ly in layers if ly.cfg.backend != "segment"]
         if bad:
@@ -180,14 +266,28 @@ class GNNServingEngine:
                                   pad=False)
         self._can_bucket = config.bucketing and all(
             ly.cfg.aggregate_op == "sum" for ly in layers)
+        # the device copy the bucket programs gather from; None where
+        # rows go from the host (other stacks, out-of-core engines, an x
+        # too large for the device)
+        self.x_device = None
+        if (self._can_bucket and not config.engn.device_budget_bytes
+                and _gathers_on_device(layers[0])):
+            self.x_device = (x_device if x_device is not None
+                             else _to_device_f32(self.x))
+        # keyed (n_pad, e_pad, resident): the bucket's program
         self._compiled: Dict = {}
         # padded_vertices: rows the device ran, summed over the
         # device_batches (a bucketed batch runs its whole bucket), so
-        # subgraph_vertices / padded_vertices is the buckets' fill
+        # subgraph_vertices / padded_vertices is the buckets' fill;
+        # resident_batches: device batches whose rows were gathered on
+        # the device; h2d_bytes: what the in-core paths sent the device
+        # for their batches (features, edges, ids; the out-of-core
+        # fallbacks stream under their own executors, uncounted)
         self.stats = {"subgraphs": 0, "subgraph_vertices": 0,
                       "device_batches": 0, "padded_vertices": 0,
                       "compiles": 0, "tiled_batches": 0,
-                      "ring_batches": 0, "warm_filled": 0}
+                      "ring_batches": 0, "warm_filled": 0,
+                      "resident_batches": 0, "h2d_bytes": 0}
         self._compat = None           # lazy inline pipeline for step/drain
         # the pipeline ticket whose device stage runs next, for the spans
         # of `_infer_batch` (which keeps its (sub, xs) signature for the
@@ -266,10 +366,12 @@ class GNNServingEngine:
 
         `x_new` replaces the feature matrix (required when vertices
         were added and features exist for them); otherwise new vertices
-        get zero feature rows.
+        get zero feature rows.  Either refreshes the device copy (which
+        goes back to the host if it no longer fits).
         """
         old_graph = self.graph
         g = snapshot.graph
+        x_changed = True
         if x_new is not None:
             x_new = np.asarray(x_new)
             if x_new.shape[0] != g.num_vertices:
@@ -281,6 +383,10 @@ class GNNServingEngine:
             pad = np.zeros((g.num_vertices - self.x.shape[0],
                             self.x.shape[1]), self.x.dtype)
             self.x = np.concatenate([self.x, pad], axis=0)
+        else:
+            x_changed = False
+        if x_changed and self.x_device is not None:
+            self.x_device = _to_device_f32(self.x)
         self.graph = g
         self.extractor = SubgraphExtractor(g)
         out = {"affected": 0, "invalidated": 0, "pin_drift": 0.0,
@@ -342,12 +448,15 @@ class GNNServingEngine:
 
     def _extract_batch(self, miss: np.ndarray, batch: int = -1):
         """Extraction stage (thread-safe, host-side): L-hop subgraph of
-        the miss set plus its gathered input features."""
+        the miss set plus its input feature rows, gathered here from the
+        host `x`, or None where the device gathers them from `x_device`."""
         with span(SERVE_EXTRACT, batch=batch):
             sub = self.extractor.extract(miss, self.num_hops,
                                          self.config.fanout)
-        with span(SERVE_GATHER, batch=batch):
-            xs = self.x[sub.vertices]
+        xs = None
+        if self.x_device is None:
+            with span(SERVE_GATHER, batch=batch):
+                xs = self.x[sub.vertices]
         self.stats["subgraphs"] += 1
         self.stats["subgraph_vertices"] += sub.graph.num_vertices
         return sub, xs
@@ -377,11 +486,14 @@ class GNNServingEngine:
     def _run_subgraph(self, seeds: np.ndarray) -> np.ndarray:
         return self._infer_batch(*self._extract_batch(seeds))
 
-    def _infer_batch(self, sub, xs: np.ndarray) -> np.ndarray:
+    def _infer_batch(self, sub, xs: Optional[np.ndarray]) -> np.ndarray:
         """Inference stage (device-side): run the stack over one
         extracted subgraph, routing over-budget batches through the
-        ring / streamed-tiled fallbacks."""
+        ring / streamed-tiled fallbacks.  `xs` is the subgraph's input
+        rows, or None on an engine with `x_device`: the bucket program
+        then gathers them there."""
         g, batch = sub.graph, self.ticket
+        resident = xs is None
         self.stats["device_batches"] += 1
         budget = self.config.engn.device_budget_bytes
         if budget and self._subgraph_footprint(g) > budget:
@@ -394,15 +506,11 @@ class GNNServingEngine:
         if not self._can_bucket:
             self.stats["padded_vertices"] += g.num_vertices
             with span(SERVE_INFER, batch=batch):
-                gd = {"n": g.num_vertices, "src": jnp.asarray(g.src),
-                      "dst": jnp.asarray(g.dst),
-                      "val": jnp.asarray(g.weights())}
-                if g.rel is not None:
-                    gd["rel"] = jnp.asarray(g.rel)
-                    gd["num_relations"] = g.num_relations
-                y = xs
+                src, dst, val, rel, y = self._to_device(
+                    g.src, g.dst, g.weights(), g.rel, xs)
+                gd = self._graph_dict(g.num_vertices, src, dst, val, rel)
                 for layer, p in zip(self.layers, self.params):
-                    y = layer.apply(p, gd, jnp.asarray(y))
+                    y = layer.apply(p, gd, y)
                 return np.asarray(y[:sub.num_seeds])
 
         # pow2-bucketed shapes, best-fit reuse: prefer the smallest
@@ -410,8 +518,8 @@ class GNNServingEngine:
         # than a fresh XLA compile); floored so small miss-sets (cache
         # hot) share one bucket instead of compiling per shrinking shape
         n_need, e_need = g.num_vertices + 1, max(g.num_edges, 1)
-        fits = [(n, e) for (n, e) in self._compiled
-                if n >= n_need and e >= e_need]
+        fits = [(n, e) for (n, e, r) in self._compiled
+                if r == resident and n >= n_need and e >= e_need]
         if fits:
             n_pad, e_pad = min(fits, key=lambda ne: ne[0] * ne[1])
         else:
@@ -434,29 +542,69 @@ class GNNServingEngine:
                 # below discards
                 rel = np.zeros(e_pad, np.int32)
                 rel[:g.num_edges] = g.rel
-            xf = np.zeros((n_pad, xs.shape[1]), np.float32)
-            xf[:xs.shape[0]] = xs
+            if resident:
+                # the device gathers the rows: the host stages their
+                # ids, then -1s for the padding rows the program zeroes
+                vids = np.full(n_pad, -1, np.int32)
+                vids[:g.num_vertices] = sub.vertices
+                inputs = (vids,)
+            else:
+                xf = np.zeros((n_pad, xs.shape[1]), np.float32)
+                xf[:xs.shape[0]] = xs
+                inputs = (xf,)
 
-        key = (n_pad, e_pad)
+        key = (n_pad, e_pad, resident)
         fn = self._compiled.get(key)
         if fn is None:
-            fn = jax.jit(partial(self._stack_fn, n_pad))
+            fn = jax.jit(partial(self._resident_fn if resident
+                                 else self._stack_fn, n_pad))
             self._compiled[key] = fn
             self.stats["compiles"] += 1
         with span(SERVE_INFER, batch=batch):
-            y = np.asarray(fn(jnp.asarray(src), jnp.asarray(dst),
-                              jnp.asarray(val),
-                              jnp.asarray(rel) if rel is not None else None,
-                              jnp.asarray(xf)))
+            args = self._to_device(src, dst, val, rel, *inputs)
+            if resident:
+                args.append(self.x_device)
+                self.stats["resident_batches"] += 1
+            y = np.asarray(fn(*args))
         return y[:sub.num_seeds]
 
-    def _stack_fn(self, n_pad, src, dst, val, rel, xf):
-        gd = {"n": n_pad, "src": src, "dst": dst, "val": val}
+    def _to_device(self, *arrays) -> list:
+        """The host arrays on the device (None stays None), counted in
+        `stats["h2d_bytes"]`."""
+        self.stats["h2d_bytes"] += sum(
+            a.nbytes for a in arrays if a is not None)
+        return [None if a is None else jnp.asarray(a) for a in arrays]
+
+    def _graph_dict(self, n, src, dst, val, rel) -> Dict:
+        gd = {"n": n, "src": src, "dst": dst, "val": val}
         if rel is not None:
             gd["rel"] = rel
             gd["num_relations"] = self.graph.num_relations
+        return gd
+
+    def _stack_fn(self, n_pad, src, dst, val, rel, xf):
+        gd = self._graph_dict(n_pad, src, dst, val, rel)
         y = xf
         for layer, p in zip(self.layers, self.params):
+            y = layer.apply(p, gd, y)
+        return y
+
+    def _resident_fn(self, n_pad, src, dst, val, rel, vids, x):
+        """`_stack_fn` over the input `x[vids]` (zero where `vids` is
+        -1), with layer 0's extraction applied block by block as the
+        rows are gathered (`gather_extract`).  `x` is `x_device`, an
+        argument, not a constant of the program."""
+        gd = self._graph_dict(n_pad, src, dst, val, rel)
+        layer, p = self.layers[0], self.params[0]
+        f = layer.cfg.in_dim                  # x's columns past f are padding
+
+        def extract(xb):
+            return layer.feature_extraction(p, xb[:, :f])
+        with scope(EXTRACT):
+            h = gather_extract(extract, x, vids,
+                               block_rows(n_pad, x.shape[1]))
+        y = layer.aggregate_update(p, gd, None, h)   # x_self is not read
+        for layer, p in zip(self.layers[1:], self.params[1:]):
             y = layer.apply(p, gd, y)
         return y
 

@@ -93,13 +93,17 @@ class ReplicatedServer:
             raise ValueError(f"replicas must be >= 1, got {replicas}")
         config = config if config is not None else ServingConfig()
         self.graph = graph
-        # ONE extractor (and one feature array) shared by every replica:
-        # both are read-only at serving time
+        # ONE extractor, one feature array and one device copy of it
+        # (where the stack gathers on the device) shared by every
+        # replica: all are read-only at serving time
         self.extractor = SubgraphExtractor(graph)
-        self.engines: List[GNNServingEngine] = [
+        first = GNNServingEngine(graph, x, layers, params, config,
+                                 extractor=self.extractor)
+        self.engines: List[GNNServingEngine] = [first] + [
             GNNServingEngine(graph, x, layers, params, config,
-                             extractor=self.extractor)
-            for _ in range(replicas)]
+                             extractor=self.extractor,
+                             x_device=first.x_device)
+            for _ in range(replicas - 1)]
         self.pipelines: List[ServingPipeline] = [
             ServingPipeline(e) for e in self.engines]
         if isinstance(balancer, str):

@@ -146,3 +146,41 @@ def test_grad_of_blocked_aggregate_compiles(spec, fmt):
     argnum = len(graph)
     _compile(jax.grad(loss, argnums=argnum), *graph,
              spec((Q * T, HIDDEN)))
+
+
+
+
+@pytest.mark.parametrize("precision", [None, "high"])
+def test_resident_serving_bucket_compiles(spec, precision):
+    """The serving bucket program that gathers its rows from the
+    resident feature matrix, at NELL's size (65,755 x 5,415 features,
+    stored as 5,504 zero-padded columns; GCN 64 -> 210) and the
+    warm-up's 131,072-row bucket.  The padded matrix takes the row-major
+    layout, so no copy of it is made, and the temporaries stay far below
+    the 2.84 GB (n_pad, F) input the host path sends: about one
+    8,192-row block at "high" (260 MiB on this compiler), and a bfloat16
+    copy of x hoisted out of the loop at the default precision."""
+    import numpy as np
+
+    from repro.core.models import init_stack, make_gnn_stack
+    from repro.graphs.generate import rmat_graph
+    from repro.serving.engine import GNNServingEngine
+
+    n, f, n_pad, e_pad = 65755, 5415, 131072, 524288
+    layers = make_gnn_stack("gcn", [f, 64, 210])
+    params = init_stack(layers, jax.random.key(0))
+    eng = GNNServingEngine(rmat_graph(300, 1200, seed=0).gcn_normalized(),
+                           np.zeros((300, f), np.float32), layers, params)
+    width = eng.x_device.shape[1]
+    edges = spec((e_pad,), jnp.int32)
+    with jax.default_matmul_precision(precision):
+        compiled = jax.jit(partial(eng._resident_fn, n_pad)).lower(
+            edges, edges, spec((e_pad,)), None, spec((n_pad,), jnp.int32),
+            spec((n, width))).compile()
+    assert compiled.input_formats[0][-1].layout.major_to_minor == (0, 1)
+    assert not [line for line in compiled.as_text().splitlines()
+                if f"f32[{n},{width}]" in line and " copy(" in line]
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < n_pad * f * 4 // 3, temp
+    if precision == "high":
+        assert temp < 2 * 8192 * width * 4, temp
