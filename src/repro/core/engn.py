@@ -34,6 +34,7 @@ records what training keeps resident).
 from __future__ import annotations
 
 import dataclasses
+from contextlib import nullcontext
 from functools import partial
 from typing import Any, Callable, Dict, Optional
 
@@ -77,6 +78,144 @@ def segment_aggregate(edge_vals: jnp.ndarray, dst: jnp.ndarray, n: int,
                                 num_segments=n)
         return s / jnp.maximum(c, 1.0)[:, None]
     raise ValueError(op)
+
+
+# The segment paths gather one row per edge.  Where those rows, counted
+# at the TPU's 128-lane width, would take more than EDGE_CHUNK_BYTES,
+# the edges are walked in chunks that stay under it.  The cap lies above
+# the largest serving bucket's gather (524,288 edges at width 64, 256
+# MiB), so every graph below it keeps the one-shot program.
+EDGE_CHUNK_BYTES = 512 << 20
+LANES = 128
+EDGE_KEYS = ("src", "dst", "val", "rel")
+
+
+def gathered_row_bytes(width: int) -> int:
+    """Device bytes of one gathered float32 row of `width` columns,
+    the columns rounded up to whole 128-lane rows."""
+    return 4 * LANES * -(-max(int(width), 1) // LANES)
+
+
+def edge_chunk(num_edges: int, width: int) -> int:
+    """Edges per chunk of a segment aggregate whose edges gather rows of
+    `width` columns: all of them when their rows fit under
+    EDGE_CHUNK_BYTES, else the largest power of two that does."""
+    rows = max(EDGE_CHUNK_BYTES // gathered_row_bytes(width), 1)
+    if num_edges <= rows:
+        return num_edges
+    return 1 << (rows.bit_length() - 1)
+
+
+def cut_edges(edges: Dict[str, Any], chunk: int, drop: int, xp=jnp
+              ) -> Dict[str, Any]:
+    """The per-edge arrays (`EDGE_KEYS`) cut into (chunks, chunk) rows.
+    The last row is padded with edges from vertex 0 to `drop`, a
+    segment past the last, of weight 0 and relation 0: the reductions
+    drop them, so they reach no vertex.  `xp` is numpy on the host."""
+    e = int(edges["src"].shape[0])
+    chunks = -(-e // chunk)
+    fill = {"src": 0, "dst": drop, "val": 0, "rel": 0}
+    return {k: xp.concatenate([a, xp.full(chunks * chunk - e, fill[k],
+                                          a.dtype)]).reshape(chunks, chunk)
+            for k, a in edges.items()}
+
+
+def reduce_edges(edges: Dict[str, Any], message: Callable,
+                 num_segments: int, width: int, op: AggregateOp = "sum",
+                 keys: Optional[Callable] = None,
+                 scope_name: Optional[str] = None) -> jnp.ndarray:
+    """Reduce one message per edge at the edge's segment, as
+    `segment_aggregate` does, in edge chunks where the gathered rows
+    would pass EDGE_CHUNK_BYTES.
+
+    `edges` holds per-edge arrays, either flat (E,) or already cut into
+    (chunks, chunk) rows by `cut_edges` (as `prepare_graph` lays out a
+    graph that needs chunks).  `message(e)` makes the rows of the edges
+    in `e`, a dict of the same keys; `keys(e)` their segment ids
+    (default `e["dst"]`); `width` is the columns one edge gathers, which
+    sizes the chunk.  Where the flat edges fit in one chunk this emits
+    exactly `segment_aggregate(message(edges), keys(edges), ...)`, with
+    the reduction in `scope_name`.  Otherwise a `lax.scan` over the
+    chunks adds (sum, mean) or maxes (max) each chunk's rows into one
+    (num_segments, ...) accumulator, under `scope_name`.  The backward
+    is autodiff through the loop; each chunk's step runs again there,
+    so the loop keeps no per-edge rows or indices for it."""
+    keys = keys or (lambda e: e["dst"])
+    ctx = (lambda: scope(scope_name)) if scope_name else nullcontext
+    total = int(edges["src"].size)
+    chunk = edge_chunk(total, width)
+    if edges["src"].ndim == 1:
+        if chunk >= total:
+            ev = message(edges)
+            with ctx():
+                return segment_aggregate(ev, keys(edges), num_segments, op)
+        # a carrier prepare_graph did not lay out (a serving batch's):
+        # cut it here
+        edges = cut_edges(edges, chunk, num_segments)
+    elif edges["src"].shape[1] > chunk:
+        # rows wider than the layout was sized for: split its rows
+        edges = {k: a.reshape(-1, chunk) for k, a in edges.items()}
+
+    def loop(fn, op):
+        """Reduce fn's rows (a tuple of arrays) over every chunk.  The
+        chunk's step is run again in the backward rather than keeping
+        its rows or indices."""
+        @jax.checkpoint
+        def body(acc, e):
+            at = [a.at[keys(e)] for a in acc]
+            rows = fn(e)
+            return tuple(a.max(r, mode="drop") if op == "max"
+                         else a.add(r, mode="drop")
+                         for a, r in zip(at, rows)), None
+        first = jax.eval_shape(fn, {k: a[0] for k, a in edges.items()})
+        init = tuple(jnp.full((num_segments,) + s.shape[1:],
+                              -jnp.inf if op == "max" else 0, s.dtype)
+                     for s in first)
+        return jax.lax.scan(body, init, edges)[0]
+
+    with ctx():
+        if op == "sum":
+            return loop(lambda e: (message(e),), "sum")[0]
+        if op == "mean":
+            s, c = loop(lambda e: (message(e), jnp.ones(e["dst"].shape,
+                                                        jnp.float32)),
+                        "sum")
+            return s / jnp.maximum(c, 1.0)[:, None]
+        if op != "max":
+            raise ValueError(op)
+        # the maxima first, then their gradient: each edge that attains
+        # its segment's maximum takes an equal share, as segment_max's
+        # own derivative gives it
+        m = loop(lambda e: (jax.lax.stop_gradient(message(e)),), "max")[0]
+
+        def attained(e):
+            rows = message(e)
+            hit = rows == m[keys(e)]
+            return (jnp.where(hit, rows - jax.lax.stop_gradient(rows), 0.0),
+                    hit.astype(rows.dtype))
+        zero, ties = loop(attained, "sum")
+        y = m + zero / jnp.maximum(ties, 1.0)
+        return jnp.where(jnp.isneginf(m), 0.0, y)
+
+
+def edge_arrays(graph: Dict[str, Any]) -> Dict[str, Any]:
+    """The per-edge arrays of a segment carrier, flat or in chunks."""
+    return {k: graph[k] for k in EDGE_KEYS if graph.get(k) is not None}
+
+
+def segment_gather_width(cfg: "EnGNConfig", out_dim: int) -> int:
+    """Columns of the rows each edge gathers on the segment backend for
+    a layer of `cfg`: the aggregated width of the default contract (the
+    extracted `out_dim` when extraction comes first, else `in_dim`), or
+    both endpoints' `in_dim` rows of the typed and gated contracts (the
+    typed contract's aggregate-first order gathers the source alone)."""
+    f = cfg.in_dim
+    order = (cfg.stage_order if cfg.stage_order != "auto"
+             else "fau" if out_dim <= f else "afu")
+    if cfg.stage_contract in ("typed", "gated"):
+        return f if (cfg.stage_contract == "typed"
+                     and order == "afu") else 2 * f
+    return out_dim if order == "fau" else f
 
 
 @dataclasses.dataclass
@@ -331,32 +470,35 @@ class EnGNLayer:
         x = jnp.asarray(x, jnp.float32 if backend == "tiled"
                         else self.cfg.dtype)
         if backend == "segment":
-            src, dst, rel = graph["src"], graph["dst"], graph["rel"]
-            val = graph.get("val")
-            val = (jnp.ones(src.shape[0], jnp.float32) if val is None
-                   else jnp.asarray(val, jnp.float32))
+            edges = self._weighted_edges(graph)
+            f = x.shape[1]
+
+            def by_rel(e):
+                return e["dst"] * r + e["rel"]
             if spec.get("normalize") and not graph.get("rel_normed"):
                 with scope(AGGREGATE):
-                    key = dst * r + rel
-                    cnt = jax.ops.segment_sum(jnp.ones_like(val), key,
-                                              num_segments=n * r)
-                    val = val / jnp.maximum(cnt[key], 1.0)
+                    cnt = reduce_edges(
+                        edges, lambda e: jnp.ones_like(e["val"]), n * r, 1,
+                        keys=by_rel)
+                    edges["val"] = edges["val"] / jnp.maximum(
+                        cnt[by_rel(edges)], 1.0)
             if self.dasr_order() == "afu":
                 # aggregate per (dst, rel) first, then one batched
                 # projection — Eq. 7's cheaper order when F < H
                 with scope(AGGREGATE):
-                    ev = x[src] * val[:, None]
-                    agg_r = jax.ops.segment_sum(ev, dst * r + rel,
-                                                num_segments=n * r)
+                    agg_r = reduce_edges(
+                        edges, lambda e: x[e["src"]] * e["val"][:, None],
+                        n * r, f, keys=by_rel)
                 with scope(EXTRACT):
                     agg = jnp.einsum("nrf,rfh->nh",
-                                     agg_r.reshape(n, r, x.shape[1]),
-                                     params["wr"])
+                                     agg_r.reshape(n, r, f), params["wr"])
             else:
-                with scope(EXTRACT):
-                    ev = self.extract(params, x[src], x[dst], val, rel)
-                with scope(AGGREGATE):
-                    agg = jax.ops.segment_sum(ev, dst, num_segments=n)
+                def message(e):
+                    with scope(EXTRACT):
+                        return self.extract(params, x[e["src"]],
+                                            x[e["dst"]], e["val"], e["rel"])
+                agg = reduce_edges(edges, message, n, 2 * f,
+                                   scope_name=AGGREGATE)
         elif backend in ("tiled", "blocked", "ring"):
             with scope(EXTRACT):
                 xw = self.src_payload(params, x)          # (n, r*h)
@@ -366,6 +508,16 @@ class EnGNLayer:
             raise ValueError(backend)
         with scope(UPDATE):
             return self.update(params, x, agg)
+
+    @staticmethod
+    def _weighted_edges(graph) -> Dict[str, Any]:
+        """The staged contracts' segment edges, with float32 weights
+        (1 where the graph has none)."""
+        edges = edge_arrays(graph)
+        val = edges.get("val")
+        edges["val"] = (jnp.ones(edges["src"].shape, jnp.float32)
+                        if val is None else jnp.asarray(val, jnp.float32))
+        return edges
 
     @staticmethod
     def _typed_sum(graph, xw, backend, n, r, h, dtype):
@@ -415,14 +567,12 @@ class EnGNLayer:
         x = jnp.asarray(x, jnp.float32 if backend == "tiled"
                         else self.cfg.dtype)
         if backend == "segment":
-            src, dst = graph["src"], graph["dst"]
-            val = graph.get("val")
-            val = (jnp.ones(src.shape[0], jnp.float32) if val is None
-                   else jnp.asarray(val, jnp.float32))
-            with scope(EXTRACT):
-                ev = self.extract(params, x[src], x[dst], val, None)
-            with scope(AGGREGATE):
-                agg = jax.ops.segment_sum(ev, dst, num_segments=n)
+            def message(e):
+                with scope(EXTRACT):
+                    return self.extract(params, x[e["src"]], x[e["dst"]],
+                                        e["val"], None)
+            agg = reduce_edges(self._weighted_edges(graph), message, n,
+                               2 * x.shape[1], scope_name=AGGREGATE)
         elif backend in ("tiled", "blocked", "ring"):
             with scope(EXTRACT):
                 ph = self.gate_dst(params, x)
@@ -563,10 +713,13 @@ class EnGNLayer:
         graph = plan_carrier(graph)   # stage entry point: plan or dict
         backend = graph.get("backend", cfg.backend)
         if backend == "segment":
-            ev = feat[graph["src"]]
-            if "val" in graph:
-                ev = ev * graph["val"][:, None]
-            return segment_aggregate(ev, graph["dst"], graph["n"], cfg.aggregate_op)
+            def message(e):
+                ev = feat[e["src"]]
+                if "val" in e:
+                    ev = ev * e["val"][:, None]
+                return ev
+            return reduce_edges(edge_arrays(graph), message, graph["n"],
+                                feat.shape[1], cfg.aggregate_op)
         if backend in ("blocked", "fused"):
             n = graph["n"]
             pad_n = graph["blocks_meta"]["padded"]
@@ -930,14 +1083,23 @@ def prepare_graph(g: COOGraph, cfg: EnGNConfig,
         return prepare_tiled(g, cfg, out_dim, rel_normed=rel_normed)
     d: Dict[str, Any] = {"n": g.num_vertices, "backend": backend}
     if backend == "segment":
-        d["src"] = jnp.asarray(g.src)
-        d["dst"] = jnp.asarray(g.dst)
-        if g.val is not None:
-            d["val"] = jnp.asarray(g.val)
+        edges = {"src": g.src, "dst": g.dst, "val": g.val, "rel": g.rel}
+        edges = {k: a for k, a in edges.items() if a is not None}
+        # a graph whose gather would pass EDGE_CHUNK_BYTES is laid out
+        # in edge chunks here, once, for reduce_edges to walk
+        width = segment_gather_width(cfg, h)
+        e = g.num_edges
+        chunk = edge_chunk(e, width)
+        if chunk < e:
+            edges = cut_edges(edges, chunk, g.num_vertices, np)
+        d.update((k, jnp.asarray(a)) for k, a in edges.items())
         if g.rel is not None:
-            d["rel"] = jnp.asarray(g.rel)
             d["num_relations"] = g.num_relations
             d["rel_normed"] = rel_normed
+        d["segment_meta"] = {
+            "edge_chunk": chunk, "chunks": -(-e // max(chunk, 1)),
+            "device_bytes": (sum(a.nbytes for a in edges.values())
+                             + chunk * gathered_row_bytes(width))}
         return wrap_plan(d)
     if (backend == "blocked" and cfg.stage_contract == "typed"
             and g.rel is not None and g.num_relations > 1):

@@ -39,8 +39,10 @@ class PreparedPlan:
     footprint_bytes: what the plan claims to occupy — device bytes for
                      resident backends (per *shard* for ring), host
                      store bytes + resident feature bytes for the
-                     streamed tiled backend.  Best-effort: 0 when the
-                     backend records no estimate (plain segment dicts).
+                     streamed tiled backend; for segment, the edge
+                     arrays and one edge chunk's gathered rows.
+                     Best-effort: 0 when the carrier records no
+                     estimate (a raw carrier dict).
     autotune:        the `kernels/autotune.py` FormatChoice record when
                      the tile format was autotuned, else None.
     carrier:         the backend-specific operand dict (device arrays,
@@ -63,10 +65,9 @@ class PreparedPlan:
     @property
     def meta(self) -> Dict[str, Any]:
         """The backend's meta block under one name: ``blocks_meta`` /
-        ``tiled_meta`` / ``ring_meta``, or {} (segment carries none)."""
-        return (self.carrier.get("blocks_meta")
-                or self.carrier.get("tiled_meta")
-                or self.carrier.get("ring_meta") or {})
+        ``tiled_meta`` / ``ring_meta`` / ``segment_meta``, or {} (a raw
+        carrier dict carries none)."""
+        return _meta(self.carrier)
 
     def __repr__(self) -> str:  # the carrier holds device arrays — elide
         return (f"PreparedPlan(backend={self.backend!r}, n={self.n}, "
@@ -74,6 +75,12 @@ class PreparedPlan:
                 f"streaming_mode={self.streaming_mode!r}, "
                 f"footprint_bytes={self.footprint_bytes}, "
                 f"keys={sorted(self.carrier)})")
+
+
+def _meta(carrier: Dict[str, Any]) -> Dict[str, Any]:
+    return (carrier.get("blocks_meta") or carrier.get("tiled_meta")
+            or carrier.get("ring_meta") or carrier.get("segment_meta")
+            or {})
 
 
 def plan_carrier(graph: Any) -> Dict[str, Any]:
@@ -90,8 +97,7 @@ def wrap_plan(carrier: Dict[str, Any]) -> PreparedPlan:
     if isinstance(carrier, PreparedPlan):        # idempotent (spill paths
         return carrier                           # return wrapped plans)
     backend = carrier.get("backend", "segment")
-    meta = (carrier.get("blocks_meta") or carrier.get("tiled_meta")
-            or carrier.get("ring_meta") or {})
+    meta = _meta(carrier)
     footprint = int(meta.get("device_bytes") or 0)
     if not footprint and backend in ("blocked", "fused"):
         # dense block carriers predate the device_bytes estimate: price

@@ -115,8 +115,11 @@ def dense_footprint_bytes(num_vertices: int, num_edges: int, in_dim: int,
     feat = act * 4 * n * (f + h)              # resident X and H
     scale_b = 4 if value_dtype == "int8" else 0   # f32 scale per group
     if backend == "segment":
+        from repro.core.engn import edge_chunk, gathered_row_bytes
         edges = e * (8 + (4 if has_val else 0))
-        return feat + edges + act * 4 * e * max(f, h)  # (E, d) gather
+        # one edge chunk's gathered rows, at the 128-lane row width
+        w = max(f, h)
+        return feat + edges + act * edge_chunk(e, w) * gathered_row_bytes(w)
     if backend in ("blocked", "fused"):
         q = -(-n // tile)
         nnzb_ub = min(q * q, max(e, 1))

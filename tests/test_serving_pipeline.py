@@ -380,7 +380,10 @@ def test_prepared_plan_round_trip(backend):
     assert plan.as_dict() is plan.carrier
     assert plan.carrier["backend"] == backend
     if backend == "segment":
-        assert plan.tile_format is None and plan.footprint_bytes == 0
+        assert plan.tile_format is None
+        # the edge-chunk counters: one chunk, under EDGE_CHUNK_BYTES
+        assert plan.meta["chunks"] == 1
+        assert plan.footprint_bytes == plan.meta["device_bytes"] > 0
     else:
         assert plan.tile_format in ("dense", "packed")
         assert plan.footprint_bytes > 0

@@ -184,3 +184,65 @@ def test_resident_serving_bucket_compiles(spec, precision):
     assert temp < n_pad * f * 4 // 3, temp
     if precision == "high":
         assert temp < 2 * 8192 * width * 4, temp
+
+
+def test_segment_train_step_at_synthetic_a_fits(spec):
+    """The segment GCN train step at Synthetic-A's full size (4.19M
+    vertices, 67.1M edges and a self loop each, 100 features, widths
+    64 and 16) as `prepare_graph` lays it out: edges in 1,048,576-edge
+    chunks.  Its arguments and temporaries fit in three quarters of the
+    chip's 16 GiB, and no floating-point buffer holds a row per edge,
+    neither the (E, d) gather of the one-shot program (36.5 GB, which
+    the chip refused) nor chunks' messages stacked for the backward."""
+    import re
+
+    from repro.core.engn import edge_chunk, segment_gather_width
+    from repro.core.models import apply_stack, make_gnn_stack
+    from repro.training.optimizer import init_opt_state
+    from repro.training.train_lib import make_gnn_train_step
+
+    n, e, dims = 4_190_000, 67_100_000 + 4_190_000, [100, 64, 16]
+    layers = make_gnn_stack("gcn", dims)
+    for layer in layers:
+        layer.cfg.training = True
+    chunk = edge_chunk(e, segment_gather_width(layers[0].cfg, dims[1]))
+    chunks = -(-e // chunk)
+    assert (chunk, chunks) == (1 << 20, 68)
+
+    def loss_fn(ps, batch, arrays):
+        src, dst, val, x, y = arrays
+        carrier = {"n": n, "backend": "segment", "src": src, "dst": dst,
+                   "val": val}
+        nodes = batch["nodes"]
+        logits = apply_stack(layers, ps, carrier, x)[nodes]
+        ll = jax.nn.log_softmax(logits, -1)
+        return -jnp.mean(jnp.take_along_axis(ll, y[nodes][:, None], 1))
+
+    i32 = jnp.int32
+    params = [{"w": spec((a, b))} for a, b in zip(dims[:-1], dims[1:])]
+    opt = jax.tree.map(lambda s: spec(s.shape, s.dtype),
+                       jax.eval_shape(init_opt_state, params))
+    edges = spec((chunks, chunk), i32)
+    compiled = make_gnn_train_step(loss_fn).lower(
+        params, opt, {"nodes": spec((256,), i32)},
+        (edges, edges, spec((chunks, chunk)), spec((n, dims[0])),
+         spec((n,), i32))).compile()
+    mem = compiled.memory_analysis()
+    used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert used < 0.75 * (16 << 30), used
+    text = compiled.as_text()
+    # the chunk loops add into their accumulators in place: no loop
+    # body copies an (n, d) buffer
+    bodies = set(re.findall(r"body=%([\w.\-]+)", text))
+    assert len(bodies) == 4, bodies
+    copies, comp = [], None
+    for line in text.splitlines():
+        if line.startswith("%"):
+            comp = line[1:].split(" ", 1)[0]
+        elif comp in bodies and f"f32[{n}," in line and " copy(" in line:
+            copies.append(line)
+    assert not copies, copies
+    for dims_text in re.findall(r"(?:f32|bf16|f16)\[([0-9,]+)\]", text):
+        shape = [int(d) for d in dims_text.split(",")]
+        assert shape[0] < e, shape
+        assert not (len(shape) > 2 and shape[0] * shape[1] >= e), shape
