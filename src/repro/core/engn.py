@@ -48,7 +48,7 @@ from repro.core.tiled import (DeviceBudgetExceeded, TiledExecutor,
                               make_streamed_aggregate)
 from repro.graphs.format import COOGraph, coo_to_blocked
 from repro.graphs.partition import tile_schedule_order
-from repro.trace import AGGREGATE, EXTRACT, UPDATE, scope
+from repro.trace import AGGREGATE, EXTRACT, LANE_PACKED, UPDATE, scope
 
 
 AggregateOp = str  # "sum" | "max" | "mean"
@@ -87,6 +87,13 @@ def segment_aggregate(edge_vals: jnp.ndarray, dst: jnp.ndarray, n: int,
 # MiB), so every graph below it keeps the one-shot program.
 EDGE_CHUNK_BYTES = 512 << 20
 LANES = 128
+# A chunk loop's (N, d) operands, the table it gathers from and the
+# accumulator it adds into, narrower than this are held lane-packed
+# (`lane_pack`).  The v5e compile lays an (N, d) float32 buffer out
+# column-major ({0,1}) up to 48 columns, so that a row's d values lie N
+# apart and its scatter-add takes d strided writes; from 64 columns on
+# it is row-major.
+LANE_DENSE_BELOW = 64
 EDGE_KEYS = ("src", "dst", "val", "rel")
 
 
@@ -120,33 +127,80 @@ def cut_edges(edges: Dict[str, Any], chunk: int, drop: int, xp=jnp
             for k, a in edges.items()}
 
 
+def lane_pack(width: int) -> int:
+    """Vertices a chunk loop holds in one 128-lane row of an (N, width)
+    operand: LANES // width below LANE_DENSE_BELOW columns, else 1, the
+    (N, width) layout itself."""
+    return LANES // width if width < LANE_DENSE_BELOW else 1
+
+
+def _pack(a: jnp.ndarray, k: int) -> jnp.ndarray:
+    """(n, d) rows as a lane-packed (ceil(n / k), k * d) buffer: row v
+    lies in row v // k, lanes (v % k) * d to (v % k + 1) * d."""
+    n, d = a.shape
+    rows = -(-n // k)
+    return jnp.pad(a, ((0, rows * k - n), (0, 0))).reshape(rows, k * d)
+
+
+def _slots(ids: jnp.ndarray, k: int) -> jnp.ndarray:
+    """(c, k, 1) mask of the slot each of `ids` takes in its packed row."""
+    return (ids % k)[:, None, None] == jnp.arange(k)[:, None]
+
+
+def _spread(r: jnp.ndarray, ids: jnp.ndarray, k: int, fill) -> jnp.ndarray:
+    """(c, d) rows as (c, k * d) packed rows: each in the slot of its id,
+    `fill` in the others."""
+    return jnp.where(_slots(ids, k), r[:, None, :], fill).reshape(
+        r.shape[0], -1)
+
+
+def _take(p: jnp.ndarray, ids: jnp.ndarray, k: int) -> jnp.ndarray:
+    """Rows `ids` of a buffer laid out by `lane_pack` (k > 1: packed).
+    A packed row's other slots are masked to zeros before the slots are
+    summed, so each id reads its own values exactly."""
+    if k == 1:
+        return p[ids]
+    rows = p[ids // k].reshape(ids.shape[0], k, -1)
+    return jnp.where(_slots(ids, k), rows, 0.0).sum(1)
+
+
 def reduce_edges(edges: Dict[str, Any], message: Callable,
                  num_segments: int, width: int, op: AggregateOp = "sum",
                  keys: Optional[Callable] = None,
-                 scope_name: Optional[str] = None) -> jnp.ndarray:
+                 scope_name: Optional[str] = None,
+                 table: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """Reduce one message per edge at the edge's segment, as
     `segment_aggregate` does, in edge chunks where the gathered rows
     would pass EDGE_CHUNK_BYTES.
 
     `edges` holds per-edge arrays, either flat (E,) or already cut into
     (chunks, chunk) rows by `cut_edges` (as `prepare_graph` lays out a
-    graph that needs chunks).  `message(e)` makes the rows of the edges
-    in `e`, a dict of the same keys; `keys(e)` their segment ids
-    (default `e["dst"]`); `width` is the columns one edge gathers, which
-    sizes the chunk.  Where the flat edges fit in one chunk this emits
-    exactly `segment_aggregate(message(edges), keys(edges), ...)`, with
-    the reduction in `scope_name`.  Otherwise a `lax.scan` over the
-    chunks adds (sum, mean) or maxes (max) each chunk's rows into one
-    (num_segments, ...) accumulator, under `scope_name`.  The backward
-    is autodiff through the loop; each chunk's step runs again there,
-    so the loop keeps no per-edge rows or indices for it."""
+    graph that needs chunks).  `message(e, rows)` makes the rows of the
+    edges in `e`, a dict of the same keys, where `rows(ids)` are the
+    rows `ids` of `table` (an (N, d) array, or None where the message
+    gathers nothing); `keys(e)` their segment ids (default `e["dst"]`);
+    `width` is the columns one edge gathers, which sizes the chunk.
+    Where the flat edges fit in one chunk this emits exactly
+    `segment_aggregate(message(edges, table.__getitem__), keys(edges),
+    ...)`, with the reduction in `scope_name`.  Otherwise a `lax.scan`
+    over the chunks adds (sum, mean) or maxes (max) each chunk's rows
+    into one accumulator, under `scope_name`.  The backward is autodiff
+    through the loop; each chunk's step runs again there, so the loop
+    keeps no per-edge rows or indices for it.
+
+    The loop lays out the table and each accumulator by `lane_pack` of
+    its width: narrow ones lane-packed, so that an edge's gather and its
+    add touch one lane row; the backward, their transposes, is packed
+    with them.  The loop then runs in scope LANE_PACKED, and the
+    (num_segments, d) result is sliced out once, after it."""
     keys = keys or (lambda e: e["dst"])
     ctx = (lambda: scope(scope_name)) if scope_name else nullcontext
+    plain = None if table is None else table.__getitem__
     total = int(edges["src"].size)
     chunk = edge_chunk(total, width)
     if edges["src"].ndim == 1:
         if chunk >= total:
-            ev = message(edges)
+            ev = message(edges, plain)
             with ctx():
                 return segment_aggregate(ev, keys(edges), num_segments, op)
         # a carrier prepare_graph did not lay out (a serving batch's):
@@ -156,44 +210,80 @@ def reduce_edges(edges: Dict[str, Any], message: Callable,
         # rows wider than the layout was sized for: split its rows
         edges = {k: a.reshape(-1, chunk) for k, a in edges.items()}
 
+    kt = 1 if table is None else lane_pack(table.shape[1])
+    ident = {"sum": 0, "max": -jnp.inf}
+    one_chunk = {k: a[0] for k, a in edges.items()}
+
+    def shapes(fn):
+        """fn's row arrays' shapes, each with its `lane_pack` (1 for a
+        row of scalars)."""
+        return [(s, lane_pack(s.shape[1]) if s.ndim == 2 else 1)
+                for s in jax.eval_shape(fn, one_chunk)]
+
     def loop(fn, op):
-        """Reduce fn's rows (a tuple of arrays) over every chunk.  The
-        chunk's step is run again in the backward rather than keeping
-        its rows or indices."""
+        """Reduce fn's rows (a tuple of arrays) over every chunk, each
+        into an accumulator laid out by its `lane_pack`: (accumulators,
+        their packs).  The chunk's step is run again in the backward
+        rather than keeping its rows or indices."""
+        first = shapes(fn)
+
         @jax.checkpoint
         def body(acc, e):
-            at = [a.at[keys(e)] for a in acc]
-            rows = fn(e)
-            return tuple(a.max(r, mode="drop") if op == "max"
-                         else a.add(r, mode="drop")
-                         for a, r in zip(at, rows)), None
-        first = jax.eval_shape(fn, {k: a[0] for k, a in edges.items()})
-        init = tuple(jnp.full((num_segments,) + s.shape[1:],
-                              -jnp.inf if op == "max" else 0, s.dtype)
-                     for s in first)
-        return jax.lax.scan(body, init, edges)[0]
+            seg = keys(e)
+            out = []
+            for a, r, (_, k) in zip(acc, fn(e), first):
+                if k > 1:
+                    a, r = a.at[seg // k], _spread(r, seg, k, ident[op])
+                else:
+                    a = a.at[seg]
+                out.append(a.max(r, mode="drop") if op == "max"
+                           else a.add(r, mode="drop"))
+            return tuple(out), None
+        init = tuple(jnp.full((num_segments,) + s.shape[1:] if k == 1
+                              else (-(-num_segments // k), k * s.shape[1]),
+                              ident[op], s.dtype)
+                     for s, k in first)
+        return jax.lax.scan(body, init, edges)[0], [k for _, k in first]
 
-    with ctx():
+    def unpacked(acc, ks):
+        """The (num_segments, ...) results of accumulators and packs."""
+        return [a if k == 1 else jax.lax.optimization_barrier(
+                    a.reshape(-1, a.shape[1] // k)[:num_segments])
+                for a, k in zip(acc, ks)]
+
+    lane_packed = kt > 1 or any(
+        k > 1 for _, k in shapes(lambda e: (message(e, plain),)))
+    # The packed region meets the rest of the program at (N, d) arrays
+    # held whole by optimization barriers, forward and backward: the v5e
+    # compiler otherwise folds the relayout into the ops beyond, and
+    # folded into a mask (a ReLU's) it takes minutes to compile.
+    with ctx(), scope(LANE_PACKED) if lane_packed else nullcontext():
+        if kt > 1:
+            table = _pack(jax.lax.optimization_barrier(table), kt)
+        rows = None if table is None else partial(_take, table, k=kt)
         if op == "sum":
-            return loop(lambda e: (message(e),), "sum")[0]
+            return unpacked(*loop(lambda e: (message(e, rows),), "sum"))[0]
         if op == "mean":
-            s, c = loop(lambda e: (message(e), jnp.ones(e["dst"].shape,
-                                                        jnp.float32)),
-                        "sum")
+            s, c = unpacked(*loop(lambda e: (message(e, rows),
+                                             jnp.ones(e["dst"].shape,
+                                                      jnp.float32)),
+                                  "sum"))
             return s / jnp.maximum(c, 1.0)[:, None]
         if op != "max":
             raise ValueError(op)
         # the maxima first, then their gradient: each edge that attains
         # its segment's maximum takes an equal share, as segment_max's
         # own derivative gives it
-        m = loop(lambda e: (jax.lax.stop_gradient(message(e)),), "max")[0]
+        (mp,), (km,) = loop(
+            lambda e: (jax.lax.stop_gradient(message(e, rows)),), "max")
 
         def attained(e):
-            rows = message(e)
-            hit = rows == m[keys(e)]
-            return (jnp.where(hit, rows - jax.lax.stop_gradient(rows), 0.0),
-                    hit.astype(rows.dtype))
-        zero, ties = loop(attained, "sum")
+            r = message(e, rows)
+            hit = r == _take(mp, keys(e), km)
+            return (jnp.where(hit, r - jax.lax.stop_gradient(r), 0.0),
+                    hit.astype(r.dtype))
+        (zero, ties), kz = loop(attained, "sum")
+        m, zero, ties = unpacked((mp, zero, ties), [km] + kz)
         y = m + zero / jnp.maximum(ties, 1.0)
         return jnp.where(jnp.isneginf(m), 0.0, y)
 
@@ -478,8 +568,8 @@ class EnGNLayer:
             if spec.get("normalize") and not graph.get("rel_normed"):
                 with scope(AGGREGATE):
                     cnt = reduce_edges(
-                        edges, lambda e: jnp.ones_like(e["val"]), n * r, 1,
-                        keys=by_rel)
+                        edges, lambda e, rows: jnp.ones_like(e["val"]),
+                        n * r, 1, keys=by_rel)
                     edges["val"] = edges["val"] / jnp.maximum(
                         cnt[by_rel(edges)], 1.0)
             if self.dasr_order() == "afu":
@@ -487,18 +577,20 @@ class EnGNLayer:
                 # projection — Eq. 7's cheaper order when F < H
                 with scope(AGGREGATE):
                     agg_r = reduce_edges(
-                        edges, lambda e: x[e["src"]] * e["val"][:, None],
-                        n * r, f, keys=by_rel)
+                        edges,
+                        lambda e, rows: rows(e["src"]) * e["val"][:, None],
+                        n * r, f, keys=by_rel, table=x)
                 with scope(EXTRACT):
                     agg = jnp.einsum("nrf,rfh->nh",
                                      agg_r.reshape(n, r, f), params["wr"])
             else:
-                def message(e):
+                def message(e, rows):
                     with scope(EXTRACT):
-                        return self.extract(params, x[e["src"]],
-                                            x[e["dst"]], e["val"], e["rel"])
+                        return self.extract(params, rows(e["src"]),
+                                            rows(e["dst"]), e["val"],
+                                            e["rel"])
                 agg = reduce_edges(edges, message, n, 2 * f,
-                                   scope_name=AGGREGATE)
+                                   scope_name=AGGREGATE, table=x)
         elif backend in ("tiled", "blocked", "ring"):
             with scope(EXTRACT):
                 xw = self.src_payload(params, x)          # (n, r*h)
@@ -567,12 +659,13 @@ class EnGNLayer:
         x = jnp.asarray(x, jnp.float32 if backend == "tiled"
                         else self.cfg.dtype)
         if backend == "segment":
-            def message(e):
+            def message(e, rows):
                 with scope(EXTRACT):
-                    return self.extract(params, x[e["src"]], x[e["dst"]],
-                                        e["val"], None)
+                    return self.extract(params, rows(e["src"]),
+                                        rows(e["dst"]), e["val"], None)
             agg = reduce_edges(self._weighted_edges(graph), message, n,
-                               2 * x.shape[1], scope_name=AGGREGATE)
+                               2 * x.shape[1], scope_name=AGGREGATE,
+                               table=x)
         elif backend in ("tiled", "blocked", "ring"):
             with scope(EXTRACT):
                 ph = self.gate_dst(params, x)
@@ -713,13 +806,13 @@ class EnGNLayer:
         graph = plan_carrier(graph)   # stage entry point: plan or dict
         backend = graph.get("backend", cfg.backend)
         if backend == "segment":
-            def message(e):
-                ev = feat[e["src"]]
+            def message(e, rows):
+                ev = rows(e["src"])
                 if "val" in e:
                     ev = ev * e["val"][:, None]
                 return ev
             return reduce_edges(edge_arrays(graph), message, graph["n"],
-                                feat.shape[1], cfg.aggregate_op)
+                                feat.shape[1], cfg.aggregate_op, table=feat)
         if backend in ("blocked", "fused"):
             n = graph["n"]
             pad_n = graph["blocks_meta"]["padded"]

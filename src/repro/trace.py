@@ -22,6 +22,10 @@ EXTRACT = "engn.extract"
 AGGREGATE = "engn.aggregate"
 UPDATE = "engn.update"
 OPTIMIZER = "engn.optimizer"
+# nested in AGGREGATE: the segment edge-chunk loops that hold their
+# narrow operands lane-packed (core/engn.py::reduce_edges); not an
+# `engn.` name, so their ops still count under AGGREGATE
+LANE_PACKED = "lane_packed"
 
 # host spans of one serving batch, in the order the batch meets them
 SERVE_PROBE = "engn.serve.probe"        # cache lookup and miss set

@@ -36,14 +36,14 @@ def small_chunks(monkeypatch):
     monkeypatch.setattr(engn, "EDGE_CHUNK_BYTES", SMALL)
 
 
-def _graph(seed, rels=None):
+def _graph(seed, rels=None, n=N):
     rng = np.random.default_rng(seed)
-    src = rng.integers(0, N, E).astype(np.int32)
-    dst = rng.integers(0, N - ISOLATED, E).astype(np.int32)
+    src = rng.integers(0, n, E).astype(np.int32)
+    dst = rng.integers(0, n - ISOLATED, E).astype(np.int32)
     val = rng.uniform(0.5, 1.5, E).astype(np.float32)
     rel = None if rels is None else rng.integers(0, rels, E).astype(
         np.int32)
-    return COOGraph(N, src, dst, val, rel, rels or 1)
+    return COOGraph(n, src, dst, val, rel, rels or 1)
 
 
 def _uniform(shape, seed, lo=-1.0, hi=1.0):
@@ -67,13 +67,24 @@ def _value_and_grad(fn, x, seed):
     return fn(x), jax.grad(lambda xx: jnp.sum(fn(xx) * r))(x)
 
 
-@pytest.mark.parametrize("op", ["sum", "max", "mean"])
-def test_chunked_aggregate_matches_one_shot(op, monkeypatch):
-    g = _graph(0)
+# rows of 1 to 32 columns are lane-packed in the loop, 128 // width
+# vertices to a 128-lane row: 123 vertices leave the last packed row
+# part-empty, 120 at width 16 fill it, so that the padding edges' segment
+# lies past the last row; 64 and 100 keep the (N, d) layout
+WIDTHS = [pytest.param(op, F, N, id=op) for op in ("sum", "max", "mean")] + [
+    pytest.param(op, width, n, id=f"{op}-w{width}-n{n}")
+    for op in ("sum", "max", "mean")
+    for width, n in [(1, N + 3), (3, N + 3), (16, N), (16, N + 3),
+                     (32, N + 3), (64, N + 3), (100, N + 3)]]
+
+
+@pytest.mark.parametrize("op,width,n", WIDTHS)
+def test_chunked_aggregate_matches_one_shot(op, width, n, monkeypatch):
+    g = _graph(0, n=n)
     cfg = EnGNConfig(in_dim=F, out_dim=F, aggregate_op=op)
     layer = EnGNLayer(cfg)
     whole, cut = _plans(g, cfg, monkeypatch)
-    x = _uniform((N, F), 1)
+    x = _uniform((n, width), 1)
     y1, g1 = _value_and_grad(partial(layer._aggregate, whole), x, 2)
     y2, g2 = _value_and_grad(partial(layer._aggregate, cut), x, 2)
     np.testing.assert_allclose(y2, y1, rtol=1e-6, atol=1e-6)
@@ -114,7 +125,9 @@ def test_chunked_max_shares_ties_as_segment_max(monkeypatch):
 
 
 @pytest.mark.parametrize("model,f,h", [("rgcn", F, H), ("rgcn", 4, 7),
-                                       ("gated_gcn", F, H)])
+                                       ("gated_gcn", F, H),
+                                       ("rgcn", 64, 16), ("rgcn", 16, 70),
+                                       ("gated_gcn", 48, 5)])
 def test_staged_contracts_chunked(model, f, h, monkeypatch):
     """The typed (extraction first, and aggregation first when h > f)
     and gated segment paths, forward and their gradients in the
@@ -134,6 +147,32 @@ def test_staged_contracts_chunked(model, f, h, monkeypatch):
     want, got = run(whole), run(cut)
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("width", [1, 3, 16, 64])
+def test_chunked_max_ties_negative_and_isolated_at_each_layout(
+        width, monkeypatch):
+    """Messages all below zero, every edge twice with the copies chunks
+    apart, and a vertex count that leaves the last packed row part-empty:
+    at each row layout the maxima are negative, vertices with no
+    in-edges read 0, and each copy of a maximum takes half its gradient,
+    as the one-shot segment_max gives them."""
+    n, half = N + 3, E // 2
+    g = _graph(16, n=n)
+    g = COOGraph(n, np.tile(g.src[:half], 2), np.tile(g.dst[:half], 2),
+                 np.tile(g.val[:half], 2))
+    cfg = EnGNConfig(in_dim=F, out_dim=F, aggregate_op="max")
+    layer = EnGNLayer(cfg)
+    whole, cut = _plans(g, cfg, monkeypatch)
+    x = _uniform((n, width), 17, lo=-2.0, hi=-0.5)
+    y1, g1 = _value_and_grad(partial(layer._aggregate, whole), x, 18)
+    y2, g2 = _value_and_grad(partial(layer._aggregate, cut), x, 18)
+    np.testing.assert_array_equal(y2, y1)
+    np.testing.assert_allclose(g2, g1, rtol=1e-6, atol=1e-6)
+    has_in = np.bincount(g.dst, minlength=n) > 0
+    assert not has_in[n - ISOLATED:].any()
+    y2 = np.asarray(y2)
+    assert (y2[has_in] < 0).all() and (y2[~has_in] == 0).all()
 
 
 @pytest.mark.parametrize("model", ["gcn", "rgcn", "gated_gcn"])
@@ -214,16 +253,13 @@ def _literal_one_shot(self, graph, feat):
                              self.cfg.aggregate_op)
 
 
-def _nell_programs():
-    """StableHLO of the NELL-sized segment train step (the trainer's
-    loss over its carrier) and of the largest serving bucket's
-    programs (host rows and resident rows)."""
-    from repro.graphs.generate import rmat_graph
-    from repro.serving.engine import GNNServingEngine
+def _train_step(n, edges, dims):
+    """The segment GCN train step (the trainer's loss over its carrier)
+    on n vertices of dims[0] features, lowered for edge arrays of shape
+    `edges`, flat or in chunks."""
     from repro.training.optimizer import init_opt_state
     from repro.training.train_lib import make_gnn_train_step
 
-    n, e, dims = 65755, 251550 + 65755, [5415, 64, 210]
     s = jax.ShapeDtypeStruct
     i32, f32 = jnp.int32, jnp.float32
     layers = make_gnn_stack("gcn", dims)
@@ -239,11 +275,23 @@ def _nell_programs():
         ll = jax.nn.log_softmax(logits, -1)
         return -jnp.mean(jnp.take_along_axis(ll, y[nodes][:, None], 1))
     params = [{"w": s((a, b), f32)} for a, b in zip(dims[:-1], dims[1:])]
-    arrays = (s((e,), i32), s((e,), i32), s((e,), f32), s((n, dims[0]), f32),
-              s((n,), i32))
+    arrays = (s(edges, i32), s(edges, i32), s(edges, f32),
+              s((n, dims[0]), f32), s((n,), i32))
     step = make_gnn_train_step(loss_fn)
-    train = step.lower(params, jax.eval_shape(init_opt_state, params),
-                       {"nodes": s((256,), i32)}, arrays).as_text()
+    return step.lower(params, jax.eval_shape(init_opt_state, params),
+                      {"nodes": s((256,), i32)}, arrays)
+
+
+def _nell_programs():
+    """StableHLO of the NELL-sized segment train step and of the largest
+    serving bucket's programs (host rows and resident rows)."""
+    from repro.graphs.generate import rmat_graph
+    from repro.serving.engine import GNNServingEngine
+
+    n, e, dims = 65755, 251550 + 65755, [5415, 64, 210]
+    s = jax.ShapeDtypeStruct
+    i32, f32 = jnp.int32, jnp.float32
+    train = _train_step(n, (e,), dims).as_text()
 
     f, n_pad, e_pad = dims[0], 131072, 524288
     sl = make_gnn_stack("gcn", dims)
@@ -266,3 +314,29 @@ def test_nell_and_serving_programs_are_the_one_shot_ones(monkeypatch):
     assert "stablehlo.while" not in got[0]
     for a, b in zip(got, want):
         assert a == b
+
+
+def test_lane_packed_scope_in_the_synthetic_a_step():
+    """The Synthetic-A step (4.19M vertices, widths 64 and 16, edges in
+    68 chunks of 1,048,576), lowered: the width-16 chunk loops, forward
+    and backward, run in scope LANE_PACKED inside engn.aggregate, which
+    the stage split of a trace still counts as the aggregate; the
+    width-64 loops keep the (N, d) layout, outside it."""
+    import re
+
+    from bench.trace_stages import stage_of
+    from repro.trace import LANE_PACKED
+
+    lowered = _train_step(4_190_000, (68, 1 << 20), [100, 64, 16])
+    names = set(re.findall(r'loc\("([^"]+)"', lowered.as_text(
+        debug_info=True)))
+    packed = {a for a in names if f"/{LANE_PACKED}/" in a}
+    loops = {a.split(f"/{LANE_PACKED}/")[0] for a in packed
+             if a.endswith(f"/{LANE_PACKED}/while")}
+    assert loops == {"jit(train_step)/jvp(engn.aggregate)",
+                     "jit(train_step)/transpose(jvp(engn.aggregate))"}
+    assert {stage_of(a) for a in packed} == {"engn.aggregate"}
+    assert {a for a in names - packed
+            if re.search(r"engn\.aggregate\)*/while$", a)} == {
+        "jit(train_step)/jvp(engn.aggregate)/while",
+        "jit(train_step)/transpose(jvp(engn.aggregate))/while"}

@@ -186,22 +186,21 @@ def test_resident_serving_bucket_compiles(spec, precision):
         assert temp < 2 * 8192 * width * 4, temp
 
 
-def test_segment_train_step_at_synthetic_a_fits(spec):
+SYNTH_A_N, SYNTH_A_E = 4_190_000, 67_100_000 + 4_190_000
+
+
+@pytest.fixture(scope="module")
+def synthetic_a_step(spec):
     """The segment GCN train step at Synthetic-A's full size (4.19M
     vertices, 67.1M edges and a self loop each, 100 features, widths
-    64 and 16) as `prepare_graph` lays it out: edges in 1,048,576-edge
-    chunks.  Its arguments and temporaries fit in three quarters of the
-    chip's 16 GiB, and no floating-point buffer holds a row per edge,
-    neither the (E, d) gather of the one-shot program (36.5 GB, which
-    the chip refused) nor chunks' messages stacked for the backward."""
-    import re
-
+    64 and 16) as `prepare_graph` lays it out, edges in 1,048,576-edge
+    chunks, compiled for the described chip."""
     from repro.core.engn import edge_chunk, segment_gather_width
     from repro.core.models import apply_stack, make_gnn_stack
     from repro.training.optimizer import init_opt_state
     from repro.training.train_lib import make_gnn_train_step
 
-    n, e, dims = 4_190_000, 67_100_000 + 4_190_000, [100, 64, 16]
+    n, e, dims = SYNTH_A_N, SYNTH_A_E, [100, 64, 16]
     layers = make_gnn_stack("gcn", dims)
     for layer in layers:
         layer.cfg.training = True
@@ -223,26 +222,73 @@ def test_segment_train_step_at_synthetic_a_fits(spec):
     opt = jax.tree.map(lambda s: spec(s.shape, s.dtype),
                        jax.eval_shape(init_opt_state, params))
     edges = spec((chunks, chunk), i32)
-    compiled = make_gnn_train_step(loss_fn).lower(
+    return make_gnn_train_step(loss_fn).lower(
         params, opt, {"nodes": spec((256,), i32)},
         (edges, edges, spec((chunks, chunk)), spec((n, dims[0])),
          spec((n,), i32))).compile()
+
+
+def _loop_body_lines(text):
+    """The lines of the program's while-loop bodies."""
+    import re
+
+    bodies = set(re.findall(r"body=%([\w.\-]+)", text))
+    lines, comp = [], None
+    for line in text.splitlines():
+        if line.startswith("%"):
+            comp = line[1:].split(" ", 1)[0]
+        elif comp in bodies:
+            lines.append(line)
+    return bodies, lines
+
+
+def test_segment_train_step_at_synthetic_a_fits(synthetic_a_step):
+    """Synthetic-A's step: its arguments and temporaries fit in three
+    quarters of the chip's 16 GiB, and no floating-point buffer holds a
+    row per edge, neither the (E, d) gather of the one-shot program
+    (36.5 GB, which the chip refused) nor chunks' messages stacked for
+    the backward."""
+    import re
+
+    n, e = SYNTH_A_N, SYNTH_A_E
+    compiled = synthetic_a_step
     mem = compiled.memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert used < 0.75 * (16 << 30), used
     text = compiled.as_text()
     # the chunk loops add into their accumulators in place: no loop
     # body copies an (n, d) buffer
-    bodies = set(re.findall(r"body=%([\w.\-]+)", text))
+    bodies, lines = _loop_body_lines(text)
     assert len(bodies) == 4, bodies
-    copies, comp = [], None
-    for line in text.splitlines():
-        if line.startswith("%"):
-            comp = line[1:].split(" ", 1)[0]
-        elif comp in bodies and f"f32[{n}," in line and " copy(" in line:
-            copies.append(line)
+    copies = [line for line in lines
+              if f"f32[{n}," in line and " copy(" in line]
     assert not copies, copies
     for dims_text in re.findall(r"(?:f32|bf16|f16)\[([0-9,]+)\]", text):
         shape = [int(d) for d in dims_text.split(",")]
         assert shape[0] < e, shape
         assert not (len(shape) > 2 and shape[0] * shape[1] >= e), shape
+
+
+def test_synthetic_a_width_16_loops_are_lane_dense(synthetic_a_step):
+    """Synthetic-A's width-16 chunk loops hold their table and their
+    accumulator lane-packed, 8 vertices to a 128-lane row of a
+    (523,750, 128) row-major buffer, where the (4.19M, 16) buffer they
+    replace is laid out column-major: no loop body has a width-16
+    operand of 4.19M rows in that layout, none copies a packed buffer,
+    and the step still fits in three quarters of the chip."""
+    n = SYNTH_A_N
+    packed = f"f32[{-(-n // 8)},128]"
+    compiled = synthetic_a_step
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < 0.75 * (16 << 30))
+    _, lines = _loop_body_lines(compiled.as_text())
+    assert not [line for line in lines if f"f32[{n},16]{{0,1" in line]
+    # the width-16 forward and backward accumulators
+    scatters = [line for line in lines
+                if line.lstrip().startswith("%fusion")
+                and f" = {packed}{{1,0:" in line]
+    assert len(scatters) == 2, scatters
+    assert not [line for line in lines
+                if (packed in line or f"f32[{n}," in line)
+                and " copy(" in line]
