@@ -1,7 +1,7 @@
 PYTHON ?= python
 export PYTHONPATH := src$(if $(PYTHONPATH),:$(PYTHONPATH))
 
-.PHONY: check check-docs test bench bench-packed serve-example dev-deps
+.PHONY: check check-docs test serve-example dev-deps
 
 # tier-1 gate — run on every PR (see .github/workflows/ci.yml)
 check:
@@ -12,14 +12,6 @@ check-docs:
 	$(PYTHON) tools/check_docs.py
 
 test: check
-
-bench:
-	$(PYTHON) -m benchmarks.run
-
-# the packed-tile perf story only (C8): streamed + blocked + ring
-# packed-vs-dense rows (+ the C9 train-step rows), BENCH_8.json summary
-bench-packed:
-	$(PYTHON) -m benchmarks.run --only tiled,ring_tiled
 
 serve-example:
 	$(PYTHON) examples/serve_gnn.py

@@ -27,7 +27,7 @@ steps with ring_shards=4), against one-chip segment; its program must
 hold a collective-permute and its four stripes must sit on four devices.
 
 Every line before the last is a JSON record of one check.  Wall times
-are bring-up times, compilation included — not benchmarks.  The last
+are bring-up times, compilation included — not speed figures.  The last
 line is `{"ok": true, "device": {...}}`; any failed check exits non-zero
 before it, a watchdog ends a phase that hangs, and without a TPU the
 script exits 2 and prints no result.

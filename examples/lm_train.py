@@ -16,12 +16,12 @@ import jax
 from repro.checkpoint.manager import CheckpointManager
 from repro.data.pipeline import SyntheticTokenStream
 from repro.distributed.fault import FaultConfig, FaultTolerantRunner
-from repro.distributed.sharding import Constrainer
 from repro.launch.mesh import single_device_mesh
 from repro.nn.config import ModelConfig
 from repro.nn import transformer as T
+from repro.nn.sharding import Constrainer
+from repro.nn.train_step import make_train_step
 from repro.training.optimizer import init_opt_state
-from repro.training.train_lib import make_train_step
 
 
 def main():

@@ -16,8 +16,8 @@ from typing import Optional, Tuple
 import jax
 
 from repro.checkpoint.manager import CheckpointManager
-from repro.distributed.sharding import param_shardings
 from repro.launch.mesh import make_elastic_mesh
+from repro.nn.sharding import param_shardings
 
 
 def _place_like_params(subtree, shardings):

@@ -18,8 +18,8 @@ accumulating partial destination results exactly as the RER array does:
 Double buffering (the C7 adaptation): while the device reduces chunk k,
 the host has already issued `jax.device_put` for chunk k+1, so on real
 hardware the tile DMA overlaps the MXU work (NeuraChip's decoupled
-fetch/compute, PAPERS.md).  `double_buffer=False` serialises the two for
-an overlap ablation (benchmarks/bench_tiled_exec.py).
+fetch/compute, PAPERS.md).  `double_buffer=False` serialises the two, as
+an overlap ablation.
 
 Tile format (DESIGN.md C8): with `tile_format="packed"` (or "auto", the
 default, when the autotuner picks it) the executor streams *packed*

@@ -1,7 +1,7 @@
 """The persistent XLA compilation cache, in one place.
 
-Entry points (`chip_smoke.py`, `launch/train.py`, `benchmarks/run.py`)
-call `enable_compile_cache()` from their `main`; importing this module
+Entry points (`chip_smoke.py`, `launch/train.py`) call
+`enable_compile_cache()` from their `main`; importing this module
 changes nothing.  When `JAX_COMPILATION_CACHE_DIR` is set, JAX already
 reads it and this helper sets no other directory.  Otherwise the cache
 lives at the fixed `<checkout>/.jax_cache` (listed in `.gitignore`):

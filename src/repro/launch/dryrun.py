@@ -26,16 +26,16 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import ARCH_IDS, get_config
-from repro.distributed.sharding import (Constrainer, make_rules,
-                                        param_pspecs)
+from repro.distributed.sharding import batch_pspec
 from repro.launch.analysis import (model_flops_estimate,
                                    roofline_from_compiled)
 from repro.launch.jaxpr_cost import traced_cost
 from repro.launch.mesh import make_production_mesh
 from repro.launch import specs as SP
 from repro.nn import transformer as T
+from repro.nn.sharding import Constrainer, make_rules, param_pspecs
+from repro.nn.train_step import make_train_step
 from repro.training.optimizer import init_opt_state
-from repro.training.train_lib import make_train_step
 
 
 def _ns(mesh, tree):
@@ -81,7 +81,6 @@ def lower_cell(arch: str, shape: str, mesh, *, q_chunk=512, loss_chunk=256,
     elif kind == "prefill":
         batch_sds = SP.train_batch_specs(cfg, seq, batch)
         extras_sds = batch_sds.get("extras")
-        from repro.distributed.sharding import batch_pspec
         tok_ps = batch_pspec(mesh, 2, seq_axis=1, rules=rules,
                              shape=(batch, seq))
 
@@ -99,7 +98,6 @@ def lower_cell(arch: str, shape: str, mesh, *, q_chunk=512, loss_chunk=256,
     elif kind == "decode":
         state_sds = SP.decode_state_specs(cfg, batch, seq)
         state_ps = SP.decode_state_pspecs(cfg, state_sds, mesh, rules)
-        from repro.distributed.sharding import batch_pspec
         tok_ps = batch_pspec(mesh, 2, rules=rules, shape=(batch, 1))
         tok_sds = SP.sds((batch, 1), jnp.int32)
 

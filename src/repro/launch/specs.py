@@ -17,7 +17,8 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.nn.config import ModelConfig
 from repro.nn import transformer as T
-from repro.distributed.sharding import batch_pspec, make_rules, mesh_shape_dict
+from repro.distributed.sharding import batch_pspec, mesh_shape_dict
+from repro.nn.sharding import make_rules
 
 SHAPES = {
     "train_4k": dict(kind="train", seq=4096, batch=256),

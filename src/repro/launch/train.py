@@ -35,14 +35,12 @@ from repro.checkpoint.manager import CheckpointManager
 from repro.configs import ARCH_IDS, get_config, get_smoke
 from repro.data.pipeline import SyntheticTokenStream
 from repro.distributed.fault import FaultConfig, FaultTolerantRunner
-from repro.distributed.sharding import (Constrainer, make_rules,
-                                        param_pspecs)
 from repro.launch.mesh import make_elastic_mesh, single_device_mesh
 from repro.launch import specs as SP
 from repro.nn import transformer as T
+from repro.nn.sharding import Constrainer, make_rules, param_pspecs
+from repro.nn.train_step import make_grad_accum_train_step, make_train_step
 from repro.training.optimizer import init_opt_state
-from repro.training.train_lib import (make_grad_accum_train_step,
-                                      make_train_step)
 
 
 def build(arch: str, *, smoke: bool, batch: int, seq: int, steps: int,

@@ -13,8 +13,8 @@ requests keep riding along in the leftover slots.
 Within a batch, vertex ids are coalesced: requests for overlapping
 frontiers (hub vertices again — zipf traffic) collapse to one inference
 row each, and results are scattered back per request.  The batcher tracks
-queue-delay and end-to-end latency percentiles (p50/p99), which
-`benchmarks/bench_serving.py` reports against requests/sec.
+queue-delay and end-to-end latency percentiles (p50/p99) for the
+engine's telemetry.
 
 The admission and completion halves are exposed separately (`admit` /
 `complete`) so the async serving pipeline (serving/pipeline.py, DESIGN.md

@@ -21,7 +21,7 @@ arrival shape
                    DAVC pinned region and hub-affinity routing
 
 A trace is a list of `TimedRequest` (arrival offset, vertex ids,
-optional SLO) and is deterministic in `seed`, so benchmarks and tests
+optional SLO) and is deterministic in `seed`, so examples and tests
 replay identical traffic across engines.  Two replay helpers cover the
 two measurement regimes: `replay_closed` (drain as fast as possible —
 throughput) and `replay_timed` (honour arrival times against the wall

@@ -10,8 +10,8 @@ import pytest
 from repro.configs import ARCH_IDS, get_config, get_smoke
 from repro.launch import specs as SP
 from repro.nn import transformer as T
+from repro.nn.train_step import make_train_step
 from repro.training.optimizer import init_opt_state
-from repro.training.train_lib import make_train_step
 
 B, S = 2, 16
 

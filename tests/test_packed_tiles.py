@@ -140,6 +140,18 @@ def test_multi_edges_merge_by_summation():
         _segment_ref(g, x, "sum"), rtol=1e-6, atol=1e-6)
 
 
+@pytest.mark.parametrize("op", ["sum", "mean"])
+def test_packed_matches_dense_on_float_weights(op):
+    """On real-valued gcn-normalised weights the two carriers sum in a
+    different order, so they agree to fp32 rounding, not bit-for-bit."""
+    g = rmat_graph(400, 3000, seed=5).gcn_normalized()
+    x = np.random.default_rng(5).normal(0, 1, (400, 8)).astype(np.float32)
+    dense = TiledExecutor(g, tile=32, chunk=4, tile_format="dense")
+    packed = TiledExecutor(g, tile=32, chunk=4, tile_format="packed")
+    np.testing.assert_allclose(packed.aggregate(x, op),
+                               dense.aggregate(x, op), rtol=0, atol=1e-5)
+
+
 def test_packed_empty_tiles_and_all_zero_rows():
     g = COOGraph(10, np.array([0], np.int32), np.array([9], np.int32),
                  np.array([2.0], np.float32))

@@ -9,11 +9,11 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import get_config, get_smoke
-from repro.distributed.sharding import (Constrainer, batch_pspec,
-                                        make_rules, param_pspecs)
+from repro.distributed.sharding import batch_pspec
 from repro.launch import specs as SP
 from repro.launch.mesh import single_device_mesh
 from repro.nn.param import ParamSpec, spec_to_pspec
+from repro.nn.sharding import Constrainer, make_rules, param_pspecs
 
 
 def test_spec_to_pspec_divisibility_fallback():

@@ -18,12 +18,11 @@ from repro.distributed.compression import (compression_ratio,
                                            quantize_int8)
 from repro.graphs.generate import rmat_graph, random_features
 from repro.nn import transformer as T
+from repro.nn.train_step import make_grad_accum_train_step, make_train_step
 from repro.training.optimizer import (AdamWConfig, adamw_update,
                                       clip_by_global_norm, global_norm,
                                       init_opt_state)
 from repro.training.schedule import cosine_schedule, wsd_schedule
-from repro.training.train_lib import (make_grad_accum_train_step,
-                                      make_train_step)
 
 
 # ---------------------------------------------------------------- optimizer

@@ -36,7 +36,7 @@ MD_FILES = sorted(
     and not any(part.startswith(".") or part == "__pycache__"
                 for part in p.relative_to(REPO).parts)
 )
-CODE_DIRS = ("src", "tests", "benchmarks", "examples", "tools")
+CODE_DIRS = ("src", "tests", "examples", "tools")
 
 LINK_RE = re.compile(r"\[[^\]]*\]\(([^)\s]+)\)")
 CITE_RE = re.compile(r"DESIGN\.md\s+([SC]\d+(?:/[SC]?\d+)*)")
