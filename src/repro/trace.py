@@ -26,6 +26,10 @@ OPTIMIZER = "engn.optimizer"
 # narrow operands lane-packed (core/engn.py::reduce_edges); not an
 # `engn.` name, so their ops still count under AGGREGATE
 LANE_PACKED = "lane_packed"
+# nested in AGGREGATE: the slot sums and the sorted scatter-add of the
+# chunk loops over destination rows (core/engn.py::reduce_edges), forward
+# and backward; not an `engn.` name either
+DST_ROWS = "dst_rows"
 
 # host spans of one serving batch, in the order the batch meets them
 SERVE_PROBE = "engn.serve.probe"        # cache lookup and miss set

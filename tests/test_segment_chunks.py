@@ -52,13 +52,21 @@ def _uniform(shape, seed, lo=-1.0, hi=1.0):
 
 
 def _plans(g, cfg, monkeypatch):
-    """(one-shot plan, chunked plan) of the same graph."""
+    """(one-shot plan, chunked plan) of the same graph: the default
+    contract's sum in destination rows of 64 slots a chunk, the rest in
+    edges."""
     whole = prepare_graph(g, cfg)
     monkeypatch.setattr(engn, "EDGE_CHUNK_BYTES", SMALL)
     cut = prepare_graph(g, cfg)
     assert whole.meta["chunks"] == 1 and whole.carrier["src"].ndim == 1
-    assert cut.meta["chunks"] == 16
-    assert cut.carrier["src"].shape == (16, 64)
+    if cut.meta["layout"] == "edges":
+        assert cut.meta["chunks"] == 16
+        assert cut.carrier["src"].shape == (16, 64)
+    else:
+        w = engn.SLOT_WIDTH
+        chunks = cut.meta["chunks"]
+        assert cut.carrier["src"].shape == (chunks, w, 64 // w)
+        assert cut.carrier["dst"].shape == (chunks, 64 // w)
     return whole, cut
 
 
@@ -198,28 +206,54 @@ def test_raw_carrier_is_cut_in_the_trace(model, monkeypatch):
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
 
 
-def test_wider_rows_split_the_layout(monkeypatch):
-    """A layout sized for 128-lane rows, reduced at 200 columns (two
-    lane rows a gathered row), is split into chunks half as long."""
+def _split_at_200_columns(op, monkeypatch):
+    """The chunk shape a layout sized for 128-lane rows is walked in at
+    200 columns (two lane rows a gathered row), which must be half as
+    long, and the chunked aggregate against the one-shot one."""
     g = _graph(13)
-    cfg = EnGNConfig(in_dim=F, out_dim=F)
+    cfg = EnGNConfig(in_dim=F, out_dim=F, aggregate_op=op)
     layer = EnGNLayer(cfg)
     whole, cut = _plans(g, cfg, monkeypatch)
     feat = _uniform((N, 200), 14)
     lowered = jax.jit(partial(layer._aggregate, cut)).lower(feat)
-    assert "tensor<32x32xi32>" in lowered.as_text()
     np.testing.assert_allclose(layer._aggregate(cut, feat),
                                layer._aggregate(whole, feat),
                                rtol=1e-6, atol=1e-6)
+    return cut.meta, lowered.as_text()
+
+
+def test_wider_rows_split_the_layout(monkeypatch):
+    """Destination rows of 64 slots a chunk, reduced at 200 columns, are
+    walked in chunks of 32 slots: twice as many, each of half the rows."""
+    meta, text = _split_at_200_columns("sum", monkeypatch)
+    w = engn.SLOT_WIDTH
+    assert meta["layout"] == "dst_rows"
+    assert f"tensor<{2 * meta['chunks']}x{w}x{32 // w}xi32>" in text
+
+
+@pytest.mark.parametrize("op", ["max", "mean"])
+def test_wider_rows_split_the_edge_layout(op, monkeypatch):
+    """16 chunks of 64 edges, reduced at 200 columns, are walked as 32
+    chunks of 32 edges."""
+    meta, text = _split_at_200_columns(op, monkeypatch)
+    assert meta["layout"] == "edges"
+    assert "tensor<32x32xi32>" in text
 
 
 def test_chunk_counters_and_footprint(small_chunks):
     g = _graph(15)
     cfg = EnGNConfig(in_dim=F, out_dim=H)
     plan = prepare_graph(g, cfg)
-    edge_bytes = 16 * 64 * (4 + 4 + 4)         # src, dst, val
-    assert plan.meta == {"edge_chunk": 64, "chunks": 16,
-                         "device_bytes": edge_bytes + 64 * 512}
+    # the default contract's sum, in destination rows of 4 slots: each
+    # of the 117 destinations takes ceil(in-degree / 4) rows, 16 a chunk
+    rows = int((-(-np.bincount(g.dst) // 4)).sum())
+    assert (engn.SLOT_WIDTH, rows) == (4, 295)
+    chunks = -(-rows // 16)
+    row_bytes = chunks * (64 * (4 + 4) + 16 * 4)   # slots' src, val; dst
+    assert plan.meta == {"layout": "dst_rows", "edge_chunk": 64,
+                         "chunks": chunks, "slot_width": 4, "rows": rows,
+                         "slot_fill": E / (4 * rows),
+                         "device_bytes": row_bytes + 64 * 512}
     assert plan.footprint_bytes == plan.meta["device_bytes"]
     # the gate prices one chunk's lane-padded rows, so a budget that
     # holds the features, the edges and one chunk keeps the segment plan
@@ -230,6 +264,125 @@ def test_chunk_counters_and_footprint(small_chunks):
     assert prepare_graph(g, EnGNConfig(
         in_dim=F, out_dim=H, device_budget_bytes=need - 1)
     ).backend == "tiled"
+
+
+def test_edge_chunk_counters(small_chunks):
+    """Where the edges stay edges (here max), each is its own row."""
+    g = _graph(15)
+    plan = prepare_graph(g, EnGNConfig(in_dim=F, out_dim=H,
+                                       aggregate_op="max"))
+    edge_bytes = 16 * 64 * (4 + 4 + 4)         # src, dst, val
+    assert plan.meta == {"layout": "edges", "edge_chunk": 64, "chunks": 16,
+                         "slot_width": 1, "rows": E, "slot_fill": 1.0,
+                         "device_bytes": edge_bytes + 64 * 512}
+
+
+def _hub_graph(n, extra=150, hub=7):
+    """`_graph`'s edges and `extra` more into vertex `hub`, whose rows
+    then pass a chunk's 64 slots."""
+    g = _graph(19, n=n)
+    rng = np.random.default_rng(20)
+    src = np.concatenate([g.src, rng.integers(0, n, extra).astype(np.int32)])
+    dst = np.concatenate([g.dst, np.full(extra, hub, np.int32)])
+    val = np.concatenate([g.val, rng.uniform(0.5, 1.5, extra).astype(
+        np.float32)])
+    return COOGraph(n, src, dst, val)
+
+
+@pytest.mark.parametrize("slots", [1, 4])
+@pytest.mark.parametrize("width,n", [(16, N), (16, N + 3), (64, N + 3)])
+def test_dst_rows_match_one_shot(slots, width, n, monkeypatch):
+    """Destination rows of 1 and 4 slots, at a packed width (16) and a
+    row-major one (64), forward and gradient, against the one-shot
+    segment_aggregate: with vertices that have no in-edges, a hub whose
+    rows lie in several chunks, and a last chunk part-filled."""
+    monkeypatch.setattr(engn, "SLOT_WIDTH", slots)
+    g = _hub_graph(n)
+    cfg = EnGNConfig(in_dim=F, out_dim=F)
+    layer = EnGNLayer(cfg)
+    whole, cut = _plans(g, cfg, monkeypatch)
+    assert cut.meta["layout"] == "dst_rows"
+    assert cut.meta["slot_width"] == slots
+    dst = np.asarray(cut.carrier["dst"])
+    assert len(np.unique(np.nonzero(dst == 7)[0])) > 1
+    assert cut.meta["rows"] % (64 // slots) and dst[-1, -1] == n
+    assert not np.isin(np.arange(n - ISOLATED, n), dst).any()
+    x = _uniform((n, width), 21)
+    y1, g1 = _value_and_grad(partial(layer._aggregate, whole), x, 22)
+    y2, g2 = _value_and_grad(partial(layer._aggregate, cut), x, 22)
+    # the hub's 160-odd terms are summed in another order
+    np.testing.assert_allclose(y2, y1, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(g2, g1, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("slots", [1, 2, 4, 8])
+def test_dst_row_layout_invariants(slots, small_chunks, monkeypatch):
+    """Rows sorted by destination, chunk by chunk and across chunks;
+    each vertex in ceil(in-degree / slots) rows whose real slots hold
+    exactly its in-edges; padded slots of weight 0 from vertex 0; the
+    rows past the last real one at vertex n."""
+    g = _hub_graph(N)
+    arrays, rows, chunk = engn.dst_row_layout(g.src, g.dst, g.val, N, 6,
+                                              slots)
+    src, val, dst = (arrays[k] for k in ("src", "val", "dst"))
+    per = 64 // slots
+    assert chunk == 64 and dst.shape == (-(-rows // per), per)
+    assert src.shape == val.shape == (dst.shape[0], slots, per)
+    flat = dst.reshape(-1)
+    assert (np.diff(flat) >= 0).all()
+    deg = np.bincount(g.dst, minlength=N)
+    assert rows == int((-(-deg // slots)).sum())
+    np.testing.assert_array_equal(np.bincount(flat[:rows], minlength=N),
+                                  -(-deg // slots))
+    assert (flat[rows:] == N).all()
+    # (row, slot) order: each row's slots side by side
+    rs = src.transpose(0, 2, 1).reshape(-1, slots)
+    rv = val.transpose(0, 2, 1).reshape(-1, slots)
+    real = rv != 0
+    assert not real[rows:].any()
+    assert (rs[~real] == 0).all() and (rv[~real] == 0).all()
+    # a row's real slots come first, and only a vertex's last row pads
+    assert (np.diff(real.astype(np.int8), axis=1) <= 0).all()
+    last = np.r_[flat[1:rows] != flat[:rows - 1], True]
+    assert real[:rows][~last].all()
+    got = sorted(zip(np.repeat(flat, slots)[real.reshape(-1)],
+                     rs[real], rv[real]))
+    assert got == sorted(zip(g.dst, g.src, g.val))
+    # the edges are placed in blocks, a thread each: one block gives the
+    # same arrays
+    monkeypatch.setattr(engn.os, "cpu_count", lambda: 1)
+    one, _, _ = engn.dst_row_layout(g.src, g.dst, g.val, N, 6, slots)
+    for k, a in arrays.items():
+        np.testing.assert_array_equal(one[k], a)
+
+
+@pytest.mark.parametrize("model,op", [("rgcn", "sum"), ("gated_gcn", "sum"),
+                                      ("gcn", "max"), ("gcn", "mean")])
+def test_other_contracts_and_ops_keep_edges(model, op, small_chunks):
+    g = _graph(23, rels=3)
+    cfg = make_gnn(model, F, H).cfg
+    cfg.aggregate_op = op
+    plan = prepare_graph(g, cfg)
+    assert plan.meta["layout"] == "edges"
+    assert plan.carrier["src"].shape == (16, 64)
+
+
+def test_dst_rows_refuse_other_contracts_and_ops(small_chunks):
+    """A carrier of destination rows handed to a layer that does not
+    reduce the default contract's sum raises; it never answers."""
+    g = _graph(24, rels=3)
+    plan = prepare_graph(g, EnGNConfig(in_dim=F, out_dim=F))
+    assert plan.meta["layout"] == "dst_rows"
+    x = _uniform((N, F), 25)
+    for op in ("max", "mean"):
+        layer = EnGNLayer(EnGNConfig(in_dim=F, out_dim=F, aggregate_op=op))
+        with pytest.raises(ValueError, match="destination rows"):
+            layer._aggregate(plan, x)
+    for model in ("rgcn", "gated_gcn"):
+        layer = make_gnn(model, F, H)
+        params = layer.init(jax.random.key(2))
+        with pytest.raises(ValueError, match="destination rows"):
+            layer.apply(params, plan, x)
 
 
 def test_chunk_length_from_the_row_bytes():
@@ -253,10 +406,11 @@ def _literal_one_shot(self, graph, feat):
                              self.cfg.aggregate_op)
 
 
-def _train_step(n, edges, dims):
+def _train_step(n, edges, dims, rows=None):
     """The segment GCN train step (the trainer's loss over its carrier)
     on n vertices of dims[0] features, lowered for edge arrays of shape
-    `edges`, flat or in chunks."""
+    `edges`, flat or in chunks, or for destination rows: `src` and `val`
+    of shape `edges`, `dst` of shape `rows`."""
     from repro.training.optimizer import init_opt_state
     from repro.training.train_lib import make_gnn_train_step
 
@@ -275,7 +429,7 @@ def _train_step(n, edges, dims):
         ll = jax.nn.log_softmax(logits, -1)
         return -jnp.mean(jnp.take_along_axis(ll, y[nodes][:, None], 1))
     params = [{"w": s((a, b), f32)} for a, b in zip(dims[:-1], dims[1:])]
-    arrays = (s(edges, i32), s(edges, i32), s(edges, f32),
+    arrays = (s(edges, i32), s(rows or edges, i32), s(edges, f32),
               s((n, dims[0]), f32), s((n,), i32))
     step = make_gnn_train_step(loss_fn)
     return step.lower(params, jax.eval_shape(init_opt_state, params),
@@ -318,16 +472,20 @@ def test_nell_and_serving_programs_are_the_one_shot_ones(monkeypatch):
 
 def test_lane_packed_scope_in_the_synthetic_a_step():
     """The Synthetic-A step (4.19M vertices, widths 64 and 16, edges in
-    68 chunks of 1,048,576), lowered: the width-16 chunk loops, forward
-    and backward, run in scope LANE_PACKED inside engn.aggregate, which
-    the stage split of a trace still counts as the aggregate; the
-    width-64 loops keep the (N, d) layout, outside it."""
+    78 chunks of 262,144 destination rows of 4 slots), lowered: the
+    width-16 chunk loops, forward and backward, run in scope LANE_PACKED
+    inside engn.aggregate, which the stage split of a trace still counts
+    as the aggregate; the width-64 loops keep the (N, d) layout, outside
+    it.  Compiled, each of the four loops sums its slots and adds or
+    gathers its rows in scope DST_ROWS, inside the same loops."""
     import re
 
     from bench.trace_stages import stage_of
-    from repro.trace import LANE_PACKED
+    from repro.trace import DST_ROWS, LANE_PACKED
 
-    lowered = _train_step(4_190_000, (68, 1 << 20), [100, 64, 16])
+    rows = (78, (1 << 20) // 4)
+    lowered = _train_step(4_190_000, (78, 4, rows[1]), [100, 64, 16],
+                          rows=rows)
     names = set(re.findall(r'loc\("([^"]+)"', lowered.as_text(
         debug_info=True)))
     packed = {a for a in names if f"/{LANE_PACKED}/" in a}
@@ -340,3 +498,10 @@ def test_lane_packed_scope_in_the_synthetic_a_step():
             if re.search(r"engn\.aggregate\)*/while$", a)} == {
         "jit(train_step)/jvp(engn.aggregate)/while",
         "jit(train_step)/transpose(jvp(engn.aggregate))/while"}
+    ops = set(re.findall(r'op_name="([^"]+)"', lowered.compile().as_text()))
+    in_loops = {a for a in ops if f"/{DST_ROWS}/" in a and "/while/" in a}
+    fwd = "jit(train_step)/jvp(engn.aggregate)"
+    bwd = "jit(train_step)/transpose(jvp(engn.aggregate))"
+    assert {a.split("/while/")[0] for a in in_loops} == {
+        fwd, bwd, f"{fwd}/{LANE_PACKED}", f"{bwd}/{LANE_PACKED}"}
+    assert {stage_of(a) for a in in_loops} == {"engn.aggregate"}

@@ -189,13 +189,19 @@ def test_resident_serving_bucket_compiles(spec, precision):
 SYNTH_A_N, SYNTH_A_E = 4_190_000, 67_100_000 + 4_190_000
 
 
+# Synthetic-A's graph (R-MAT, graph_seed 0, self loops) as destination
+# rows of SLOT_WIDTH = 4 slots: 20.23M rows, 78 chunks of 262,144 rows
+SYNTH_A_ROW_CHUNKS = 78
+
+
 @pytest.fixture(scope="module")
 def synthetic_a_step(spec):
     """The segment GCN train step at Synthetic-A's full size (4.19M
     vertices, 67.1M edges and a self loop each, 100 features, widths
-    64 and 16) as `prepare_graph` lays it out, edges in 1,048,576-edge
-    chunks, compiled for the described chip."""
-    from repro.core.engn import edge_chunk, segment_gather_width
+    64 and 16) as `prepare_graph` lays it out, destination rows in
+    chunks of 1,048,576 slots, compiled for the described chip."""
+    from repro.core.engn import (SLOT_WIDTH, edge_chunk,
+                                 segment_gather_width)
     from repro.core.models import apply_stack, make_gnn_stack
     from repro.training.optimizer import init_opt_state
     from repro.training.train_lib import make_gnn_train_step
@@ -205,8 +211,9 @@ def synthetic_a_step(spec):
     for layer in layers:
         layer.cfg.training = True
     chunk = edge_chunk(e, segment_gather_width(layers[0].cfg, dims[1]))
-    chunks = -(-e // chunk)
-    assert (chunk, chunks) == (1 << 20, 68)
+    assert (chunk, SLOT_WIDTH) == (1 << 20, 4)
+    rows = (SYNTH_A_ROW_CHUNKS, chunk // SLOT_WIDTH)
+    slots = (SYNTH_A_ROW_CHUNKS, SLOT_WIDTH, chunk // SLOT_WIDTH)
 
     def loss_fn(ps, batch, arrays):
         src, dst, val, x, y = arrays
@@ -221,11 +228,10 @@ def synthetic_a_step(spec):
     params = [{"w": spec((a, b))} for a, b in zip(dims[:-1], dims[1:])]
     opt = jax.tree.map(lambda s: spec(s.shape, s.dtype),
                        jax.eval_shape(init_opt_state, params))
-    edges = spec((chunks, chunk), i32)
     return make_gnn_train_step(loss_fn).lower(
         params, opt, {"nodes": spec((256,), i32)},
-        (edges, edges, spec((chunks, chunk)), spec((n, dims[0])),
-         spec((n,), i32))).compile()
+        (spec(slots, i32), spec(rows, i32), spec(slots),
+         spec((n, dims[0])), spec((n,), i32))).compile()
 
 
 def _loop_body_lines(text):
@@ -292,3 +298,27 @@ def test_synthetic_a_width_16_loops_are_lane_dense(synthetic_a_step):
     assert not [line for line in lines
                 if (packed in line or f"f32[{n}," in line)
                 and " copy(" in line]
+
+
+def test_synthetic_a_loops_add_destination_rows(synthetic_a_step):
+    """Each of Synthetic-A's four chunk loops, forward and backward,
+    sums its rows' slots and adds (forward) or gathers (backward) one
+    row per destination row in scope DST_ROWS, inside engn.aggregate.
+    The forward loops add by indices sorted in the layout, so only the
+    backward's scatter-adds by source still sort theirs."""
+    import re
+
+    from repro.trace import DST_ROWS, LANE_PACKED
+
+    _, lines = _loop_body_lines(synthetic_a_step.as_text())
+
+    def op_name(line):
+        m = re.search(r'op_name="([^"]+)"', line)
+        return m.group(1) if m else ""
+    fwd = "jit(train_step)/jvp(engn.aggregate)"
+    bwd = "jit(train_step)/transpose(jvp(engn.aggregate))"
+    rows = {op_name(line).split("/while/")[0] for line in lines
+            if f"/{DST_ROWS}/" in op_name(line)}
+    assert rows == {fwd, bwd, f"{fwd}/{LANE_PACKED}", f"{bwd}/{LANE_PACKED}"}
+    sorts = [op_name(line) for line in lines if " sort(" in line]
+    assert len(sorts) == 2 and all(a.startswith(bwd) for a in sorts), sorts
